@@ -1,9 +1,8 @@
-"""Kernel microbenchmarks: Pallas kernels (interpret mode on this CPU
-container) validated against the jnp oracles, plus timing of the jitted
-oracle path (the number that is meaningful on CPU).
+"""Kernel microbenchmarks: the Pallas kernels, through ``kernels/ops.py``,
+validated against the jnp oracles, plus timing of the jitted oracle path.
 
-On a real TPU set REPRO_PALLAS_COMPILE=1 and the same entry points give
-compiled-kernel timings.
+``ops.py`` interprets the kernels on the CPU backend and compiles them
+everywhere else, so on a TPU the same entry points run compiled kernels.
 """
 from __future__ import annotations
 
@@ -19,7 +18,7 @@ from repro.kernels import ops, ref
 
 def run(rep: Optional[Reporter] = None) -> None:
     rep = rep or Reporter()
-    rep.section("kernels: interpret-mode allclose + jnp-oracle timing")
+    rep.section("kernels: allclose vs jnp oracles + oracle timing")
     key = jax.random.PRNGKey(0)
 
     # flash attention

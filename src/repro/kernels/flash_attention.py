@@ -68,9 +68,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
                         block_q: int = 256, block_k: int = 512,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q, k, v: (B, H, S, hd) (pre-grouped; GQA callers repeat or group
-    outside). Returns (B, H, S, hd) in q.dtype."""
+    outside). Returns (B, H, S, hd) in q.dtype. ``interpret=True`` runs
+    the kernel body as jnp (any backend); ``False`` compiles it with
+    Mosaic (TPU only)."""
     B, H, Sq, hd = q.shape
     Skv = k.shape[2]
     bq = min(block_q, Sq)

@@ -1,16 +1,15 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel
-body runs as traced jnp on CPU); on a real TPU set REPRO_PALLAS_COMPILE=1
-to compile them natively.
+The kernels compile with Mosaic on a TPU. On the CPU backend, where
+Mosaic cannot run, they execute in interpret mode (the kernel body runs
+as traced jnp). Any other backend compiles, so a kernel that cannot be
+lowered there fails loudly instead of silently interpreting.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fused_adam import fused_adam
@@ -18,9 +17,7 @@ from repro.kernels.selective_scan import selective_scan_fwd
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE"):
-        return False
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal",))

@@ -55,10 +55,12 @@ def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
 
 
 def selective_scan_fwd(x, dt, A, Bc, Cc, D, *, block_d: int = 256,
-                       block_t: int = 128, interpret: bool = True
+                       block_t: int = 128, interpret: bool
                        ) -> Tuple[jax.Array, jax.Array]:
     """x, dt: (B,S,di); Bc,Cc: (B,S,st); A: (di,st); D: (di,).
-    Returns (y: (B,S,di), h_final: (B,di,st) f32)."""
+    Returns (y: (B,S,di), h_final: (B,di,st) f32). ``interpret=True``
+    runs the kernel body as jnp (any backend); ``False`` compiles it
+    with Mosaic (TPU only)."""
     B, S, di = x.shape
     st = A.shape[-1]
     bd = min(block_d, di)

@@ -21,7 +21,8 @@ def test_flash_attention_sweep(B, H, S, hd, dtype, causal):
     q = jax.random.normal(ks[0], (B, H, S, hd), dtype)
     k = jax.random.normal(ks[1], (B, H, S, hd), dtype)
     v = jax.random.normal(ks[2], (B, H, S, hd), dtype)
-    out = flash_attention_fwd(q, k, v, causal=causal, block_q=128, block_k=128)
+    out = flash_attention_fwd(q, k, v, causal=causal, block_q=128, block_k=128,
+                              interpret=True)
     want = ref.ref_attention(q, k, v, causal=causal)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -36,7 +37,8 @@ def test_flash_attention_block_shapes(blocks):
     q = jax.random.normal(ks[0], (1, 2, 256, 64))
     k = jax.random.normal(ks[1], (1, 2, 256, 64))
     v = jax.random.normal(ks[2], (1, 2, 256, 64))
-    out = flash_attention_fwd(q, k, v, block_q=bq, block_k=bk)
+    out = flash_attention_fwd(q, k, v, block_q=bq, block_k=bk,
+                              interpret=True)
     want = ref.ref_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
@@ -52,7 +54,8 @@ def test_selective_scan_sweep(B, S, di, st):
     Bc = jax.random.normal(ks[3], (B, S, st))
     Cc = jax.random.normal(ks[4], (B, S, st))
     D = jnp.ones((di,))
-    y, h = selective_scan_fwd(x, dt, A, Bc, Cc, D, block_d=128, block_t=32)
+    y, h = selective_scan_fwd(x, dt, A, Bc, Cc, D, block_d=128, block_t=32,
+                              interpret=True)
     yr, hr = ref.ref_selective_scan(x, dt, A, Bc, Cc, D)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-4)
     np.testing.assert_allclose(np.asarray(h), np.asarray(hr), atol=1e-4)
@@ -69,7 +72,7 @@ def test_selective_scan_matches_model_scan():
     Bc = jax.random.normal(ks[3], (B, S, st))
     Cc = jax.random.normal(ks[4], (B, S, st))
     D = jnp.ones((di,))
-    y1, h1 = selective_scan_fwd(x, dt, A, Bc, Cc, D)
+    y1, h1 = selective_scan_fwd(x, dt, A, Bc, Cc, D, interpret=True)
     y2, h2 = model_scan(x, dt, A, Bc, Cc, D)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-4)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-4)
@@ -83,7 +86,7 @@ def test_fused_adam_sweep(n, step):
     m = jax.random.normal(ks[1], (n,)) * 0.1
     v = jnp.abs(jax.random.normal(ks[2], (n,))) * 0.01
     g = jax.random.normal(ks[3], (n,))
-    p2, m2, v2, lp = fused_adam(p, m, v, g, step, lr=1e-2)
+    p2, m2, v2, lp = fused_adam(p, m, v, g, step, lr=1e-2, interpret=True)
     pr, mr, vr = ref.ref_adam(p, m, v, g, step, lr=1e-2)
     np.testing.assert_allclose(np.asarray(p2), np.asarray(pr), atol=1e-6)
     np.testing.assert_allclose(np.asarray(m2), np.asarray(mr), atol=1e-7)
@@ -101,9 +104,11 @@ def test_fused_adam_partial_matches_two_stage():
     m = jnp.zeros((n,))
     v = jnp.zeros((n,))
     g = jax.random.normal(ks[3], (n,))
-    pf, mf, vf, _ = fused_adam(p, m, v, g, step, lr=1e-2)
-    p1, m1, v1, _ = fused_adam(p, m, v, g, step, lo=0, hi=k, lr=1e-2)
-    p2, m2, v2, _ = fused_adam(p1, m1, v1, g, step, lo=k, hi=n, lr=1e-2)
+    pf, mf, vf, _ = fused_adam(p, m, v, g, step, lr=1e-2, interpret=True)
+    p1, m1, v1, _ = fused_adam(p, m, v, g, step, lo=0, hi=k, lr=1e-2,
+                             interpret=True)
+    p2, m2, v2, _ = fused_adam(p1, m1, v1, g, step, lo=k, hi=n, lr=1e-2,
+                             interpret=True)
     np.testing.assert_allclose(np.asarray(p2), np.asarray(pf), atol=1e-7)
     np.testing.assert_allclose(np.asarray(m2), np.asarray(mf), atol=1e-7)
     np.testing.assert_allclose(np.asarray(v2), np.asarray(vf), atol=1e-7)
