@@ -319,7 +319,7 @@ class StripedFiles:
                 # from an overlapping write may still land there
                 claims.pop(old, None)
         if moved_off is not None:
-            eng.note_failover(moved_off, new_p, name, c)
+            eng.note_failover()
         return new_p, slot, True
 
     # ---------------- bulk ops ----------------
@@ -362,7 +362,7 @@ class StripedFiles:
                 if self.integrity and complete:
                     want = self._crc_of(name, c)
                     if want is not None and crc32c(mv) != want:
-                        eng.note_integrity_error(p, name, c)
+                        eng.note_integrity_error()
                         raise IntegrityError(
                             f"CRC32C mismatch on {name!r} chunk {c} "
                             f"(path {p}): stored bytes do not match "
@@ -416,7 +416,7 @@ class StripedFiles:
                 last = e
                 exclude.add(q)
                 continue
-            eng.note_failover(failed, q, name, c)
+            eng.note_failover()
             return
 
     def _positioned(self, name: str, data_u8: np.ndarray, byte_lo: int,

@@ -47,15 +47,21 @@ each byte's time went:
 When the shared :class:`repro.obs.Tracer` is enabled, each worker
 records TWO spans per executed request on its own track (= one Chrome
 trace row per thread): a *queue-wait* span (``t_submit`` -> start; how
-long the priority heap held it) and a *transfer* span (start ->
-settle; how long the body ran), both tagged with route, priority
-class, nbytes, and — on channel threads — the SSD path index. A
-cancelled-while-queued request records nothing (no bytes moved). With
-the tracer disabled the only cost is one flag test per submit/run,
-measured by the bench-smoke gate.
+long the priority heap held it; ring-only, since it is stamped from
+two threads; only for requests submitted while tracing) and a
+*transfer* span (start -> settle; how long the body ran, a
+``Tracer.span`` and so also on the JAX profiler's clock), both tagged
+with route, priority class, nbytes, and — on channel threads — the SSD
+path index. A cancelled-while-queued request records nothing
+(no bytes moved). A ``submit`` that has to wait for the in-flight
+budget records one ``io.budget_wait`` span on the caller's thread,
+around the wait alone. With the tracer disabled the only cost is one
+flag test per submit/run; the chip benchmark's untraced runs measure
+it.
 """
 from __future__ import annotations
 
+import contextlib
 import enum
 import errno as _errno
 import heapq
@@ -70,8 +76,8 @@ from repro.io.bandwidth import BandwidthSimulator, PathBandwidthSimulator
 from repro.io.config import PATH_POLICIES, IOConfig
 from repro.io.integrity import IntegrityError
 from repro.io.staging import StagingPool
-from repro.obs.tracer import (CAT_FAULT, CAT_IO_CHUNK, CAT_IO_QUEUE,
-                              CAT_IO_REQ, CAT_IO_REQ_QUEUE)
+from repro.obs.tracer import (CAT_IO_CHUNK, CAT_IO_QUEUE, CAT_IO_REQ,
+                              CAT_IO_REQ_QUEUE, CAT_WAIT, NO_SPAN)
 
 
 class IOPriority(enum.IntEnum):
@@ -250,27 +256,25 @@ class _PriorityWorkers:
             if not req.future.set_running_or_notify_cancel():
                 continue                         # cancelled while queued
             tr = self._tracer
-            rec = tr is not None and tr.enabled and req.t_submit > 0.0
-            if rec:
-                t_start = time.perf_counter()
+            rec = tr is not None and tr.enabled
             with self._cv:
                 self._running += 1
             try:
-                req.future.set_result(req.fn())
+                with self._transfer_span(tr, req) if rec else NO_SPAN:
+                    req.future.set_result(req.fn())
             except BaseException as e:           # propagate via the future
                 req.future.set_exception(e)
             finally:
                 with self._cv:
                     self._running -= 1
-                if rec:
-                    t_end = time.perf_counter()
-                    self._record(tr, req, t_start, t_end)
                 if req._engine is not None and req._settle_once():
                     req._engine._on_done(req)
 
-    def _record(self, tr, req: IORequest, t_start: float, t_end: float):
-        """Queue-wait + transfer spans for one executed request, on this
-        worker thread's track."""
+    @contextlib.contextmanager
+    def _transfer_span(self, tr, req: IORequest):
+        """The transfer span of one executed request on this worker
+        thread's track, and the queue-wait span that ended where it
+        starts (when the request was submitted while tracing)."""
         track = threading.current_thread().name
         args = {"route": req.route, "nbytes": req.nbytes,
                 "priority": IOPriority(req.priority).name}
@@ -281,9 +285,11 @@ class _PriorityWorkers:
             name = req.route or "chunk"
             cat_q, cat_x = CAT_IO_QUEUE, CAT_IO_CHUNK
             args["path"] = self._path_index
-        tr.record(track, name + ":wait", cat_q, req.t_submit, t_start,
-                  **args)
-        tr.record(track, name, cat_x, t_start, t_end, **args)
+        with tr.span(track, name, cat_x, **args) as sp:
+            if req.t_submit > 0.0:
+                tr.record(track, name + ":wait", cat_q, req.t_submit,
+                          sp.t0, **args)
+            yield
 
     def shutdown(self, wait: bool = True):
         with self._cv:
@@ -387,9 +393,14 @@ class IOEngine:
         """
         nbytes = int(nbytes)
         with self._bp_cv:
-            while (not self._closed and self._inflight > 0
-                   and self._inflight + nbytes > self._budget):
-                self._bp_cv.wait()
+            if self._over_budget(nbytes):
+                tr = self.tracer
+                with tr.span(threading.current_thread().name,
+                             "io.budget_wait", CAT_WAIT, category=category,
+                             nbytes=nbytes, inflight=self._inflight) \
+                        if tr is not None and tr.enabled else NO_SPAN:
+                    while self._over_budget(nbytes):
+                        self._bp_cv.wait()
             if self._closed:
                 raise RuntimeError("I/O engine is shut down")
             self._inflight += nbytes
@@ -408,6 +419,12 @@ class IOEngine:
             self._release_bytes(nbytes)
             raise
         return req
+
+    def _over_budget(self, nbytes: int) -> bool:
+        """Must a submit of ``nbytes`` wait for the in-flight budget?
+        (Called under ``_bp_cv``.)"""
+        return (not self._closed and self._inflight > 0
+                and self._inflight + nbytes > self._budget)
 
     def _release_bytes(self, nbytes: int):
         with self._bp_cv:
@@ -428,7 +445,7 @@ class IOEngine:
 
     # ---------------- chunk level ----------------
     def _with_retry(self, fn: Callable, priority: IOPriority,
-                    path_index: int, route: str) -> Callable:
+                    path_index: int) -> Callable:
         """Wrap a chunk op in the bounded transient-retry loop: each
         attempt after a :func:`is_transient` fault backs off
         exponentially from ``retry_backoff_s``, bounded by BOTH the
@@ -453,12 +470,6 @@ class IOEngine:
                         self._stats["chunk_retries"] += 1
                     with self._backlog_lock:
                         self._path_retries[path_index] += 1
-                    tr = self.tracer
-                    if tr is not None and tr.enabled:
-                        tr.instant(threading.current_thread().name,
-                                   "retry", CAT_FAULT, path=path_index,
-                                   route=route, attempt=attempt + 1,
-                                   error=repr(e))
                     if delay > 0:
                         time.sleep(delay)
                     spent += delay
@@ -476,7 +487,7 @@ class IOEngine:
         channel-backlog counters (:meth:`route_backlog`, ``depth()``)
         the adaptive lookahead throttles on."""
         if self._retries > 0:
-            fn = self._with_retry(fn, priority, path_index, route)
+            fn = self._with_retry(fn, priority, path_index)
         req = IORequest(priority, next(self._seq), "", route, nbytes, fn,
                         None)
         tr = self.tracer
@@ -634,27 +645,17 @@ class IOEngine:
             self._placed_bytes[p] += nbytes
             return p
 
-    def note_failover(self, from_path: int, to_path: int, name: str,
-                      chunk: int):
-        """Account one chunk write rerouted off a failing path (counter
-        + tracer instant); called by ``StripedFiles``."""
+    def note_failover(self):
+        """Account one chunk write rerouted off a failing path; called
+        by ``StripedFiles``."""
         with self._stats_lock:
             self._stats["chunk_failovers"] += 1
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            tr.instant(threading.current_thread().name, "failover",
-                       CAT_FAULT, from_path=from_path, to_path=to_path,
-                       name=name, chunk=chunk)
 
-    def note_integrity_error(self, path_index: int, name: str, chunk: int):
-        """Account one CRC mismatch (counter + tracer instant); called
-        by ``StripedFiles`` just before raising ``IntegrityError``."""
+    def note_integrity_error(self):
+        """Account one CRC mismatch; called by ``StripedFiles`` just
+        before raising ``IntegrityError``."""
         with self._stats_lock:
             self._stats["integrity_errors"] += 1
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            tr.instant(threading.current_thread().name, "crc_mismatch",
-                       CAT_FAULT, path=path_index, name=name, chunk=chunk)
 
     @property
     def inflight_bytes(self) -> int:

@@ -5,9 +5,14 @@ Three pieces, layered bottom-up:
 
 * :class:`Tracer` (``obs.tracer``) — the thread-safe, ring-buffered
   flight recorder every instrumented layer shares. Off by default; the
-  disabled path is one flag test per site. Spans carry plan-op identity
-  from the executor, queue-wait/transfer splits from the ``IOEngine``
-  channel threads, and hint lifecycles from the coordinators.
+  disabled path is one flag test per site, and the chip benchmark's
+  untraced runs measure its cost. Spans carry plan-op identity from
+  the executor, queue-wait/transfer splits from the ``IOEngine``
+  channel threads, hint lifecycles from the coordinators, and the
+  waits on the host's critical path where they happen: host<->device
+  copies, device waits, the in-flight budget, the α gate and the host
+  Adam's reads, update and writes. ``Tracer.span`` also stamps each on
+  the JAX profiler's clock; ``totals()`` sums them by name;
   ``export_chrome(path)`` writes Perfetto-loadable Chrome trace-event
   JSON.
 * ``build_snapshot`` (``obs.registry``) — the versioned flat
@@ -25,13 +30,15 @@ from repro.obs.reconcile import (Reconciliation, ReconRow, STALL_STREAM,
                                  top_stall_stream)
 from repro.obs.registry import (SNAPSHOT_VERSION, build_serve_snapshot,
                                 build_snapshot, traffic_maps)
-from repro.obs.tracer import (CAT_HINT, CAT_IO_CHUNK, CAT_IO_QUEUE,
-                              CAT_IO_REQ, CAT_IO_REQ_QUEUE, CAT_PLAN,
-                              Tracer)
+from repro.obs.tracer import (CAT_COPY, CAT_HINT, CAT_IO_CHUNK,
+                              CAT_IO_QUEUE, CAT_IO_REQ, CAT_IO_REQ_QUEUE,
+                              CAT_PLAN, CAT_WAIT, CAT_WORK, EXEC_TRACK,
+                              NO_SPAN, Tracer)
 
 __all__ = [
     "Tracer", "CAT_PLAN", "CAT_HINT", "CAT_IO_CHUNK", "CAT_IO_QUEUE",
-    "CAT_IO_REQ", "CAT_IO_REQ_QUEUE",
+    "CAT_IO_REQ", "CAT_IO_REQ_QUEUE", "CAT_COPY", "CAT_WAIT", "CAT_WORK",
+    "EXEC_TRACK", "NO_SPAN",
     "SNAPSHOT_VERSION", "build_snapshot", "build_serve_snapshot",
     "traffic_maps",
     "Reconciliation", "ReconRow", "STALL_STREAM", "reconcile",

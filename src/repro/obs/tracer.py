@@ -5,8 +5,8 @@ the plan executor, the ``IOEngine`` channel threads, and the hint
 coordinators. It is **off by default**: recording is gated by the
 single ``enabled`` flag, and every instrumentation site tests that flag
 *before* taking timestamps or building args, so the disabled path is
-one attribute read per site (nothing measurable; acceptance-gated by
-the paced-SSD smoke in ``check_smoke.py``).
+one attribute read per site. The chip benchmark's untraced runs
+(``benchmarks/chip/``, tracing off) measure what that costs.
 
 Spans live in a bounded ring (``collections.deque(maxlen=...)``) under
 one lock — a long traced run degrades to "most recent N spans" instead
@@ -16,6 +16,16 @@ are honest about truncation. Each span is a flat tuple
 event. Tracks map 1:1 onto Chrome trace ``tid``s: one per I/O channel
 thread (queue-wait + transfer slices), one for the plan executor, and
 one per hint stream.
+
+Sites that time work where it happens use ``span(track, name, cat,
+**args)``, a context manager. Besides the ring span (on
+``perf_counter``, as ``record``), it opens a
+``jax.profiler.TraceAnnotation`` of the same name and args, so a
+running JAX profiler stamps the span on its own clock, on the thread
+that did the work, beside the device's operations. A span stamped from
+another thread than the one it times (the I/O queue waits) goes through
+``record`` and stays ring-only. ``totals()`` sums the ring's complete
+spans by name.
 
 ``export_chrome(path)`` writes the Chrome trace-event JSON format
 (``{"traceEvents": [...]}`` with ``ph="X"`` complete events, ``ph="i"``
@@ -32,6 +42,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 Span = Tuple[str, str, str, float, Optional[float], Optional[dict]]
 
 #: Span categories (the ``cat`` field). Queue-wait and execution are
@@ -42,7 +54,13 @@ CAT_IO_REQ = "io.req"          # request-body execution (front pool)
 CAT_IO_REQ_QUEUE = "io.req.queue"
 CAT_PLAN = "plan"              # one span per executed plan op
 CAT_HINT = "hint"              # hint lifecycle (issued -> outcome)
-CAT_FAULT = "io.fault"         # instants: retries, failovers, CRC errors
+CAT_COPY = "copy"              # host<->device copy on the calling thread
+CAT_WAIT = "wait"              # a thread blocked on other work
+CAT_WORK = "work"              # reads, writes and Adam in a request body
+
+#: the plan executor's track: its op spans and the leaf spans of the
+#: copies and waits that run inside them on the executor's thread
+EXEC_TRACK = "exec"
 
 
 def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
@@ -63,6 +81,50 @@ def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
             cur_hi = hi
     total += cur_hi - cur_lo
     return total
+
+
+class _NoSpan:
+    """What ``span`` gives when the tracer is off: enters and exits,
+    reads no clock."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+#: the shared do-nothing span; a site whose args cost something to
+#: build writes ``tr.span(...) if tr.enabled else NO_SPAN``
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One open span: a profiler annotation around a ring span."""
+    __slots__ = ("_tracer", "_track", "_name", "_cat", "_args", "_ann",
+                 "t0")
+
+    def __init__(self, tracer: "Tracer", track: str, name: str, cat: str,
+                 args: dict):
+        self._tracer = tracer
+        self._track = track
+        self._name = name
+        self._cat = cat
+        self._args = args
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tracer.record(self._track, self._name, self._cat, self.t0,
+                            t1, **self._args)
+        return False
 
 
 class Tracer:
@@ -116,11 +178,28 @@ class Tracer:
     def instant(self, track: str, name: str, cat: str, **args):
         self.record(track, name, cat, time.perf_counter(), None, **args)
 
+    def span(self, track: str, name: str, cat: str, **args):
+        """Context manager timing its body as one span (see the module
+        docstring). Off: ``NO_SPAN``, one flag test and no clock read."""
+        if not self.enabled:
+            return NO_SPAN
+        return _Span(self, track, name, cat, args)
+
     def spans(self) -> List[Span]:
         with self._lock:
             return list(self._spans)
 
     # ---------------- reduction ----------------
+    def totals(self) -> Dict[str, float]:
+        """Seconds per span name, summed over the ring's complete spans
+        (instants left out). Concurrent spans of one name each count:
+        worker-side names give busy seconds, not wall time."""
+        out: Dict[str, float] = {}
+        for _track, name, _cat, t0, t1, _args in self.spans():
+            if t1 is not None:
+                out[name] = out.get(name, 0.0) + (t1 - t0)
+        return out
+
     def summary(self) -> dict:
         """Flat aggregates for ``metrics_snapshot()``: per-route chunk
         transfer time/bytes and queue-wait time, measured from the

@@ -58,7 +58,11 @@ from issue to settlement, named ``<stream>:<outcome>`` with outcome
 ``cancelled`` (reset/teardown/queued-cancel before use) or ``unused``
 (landed but the consumer had a cheaper source) — co-located with the
 ``la_hits``/``la_misses`` increments so the trace and the counters can
-never disagree.
+never disagree. Every host<->device copy goes through ``_to_device`` /
+``_to_host``, which, traced, time it as ``exec.h2d`` or as
+``exec.device_wait`` then ``exec.d2h``; the waits on other threads'
+work (``exec.param_wait``, ``opt.early_wait``, the α gate) and the
+optimizer's reads, Adam and writes get spans where they happen.
 
 All three submit their asynchronous work to :class:`repro.io.IOEngine`
 rather than raw executors, so a parameter fetch the GPU is about to
@@ -67,6 +71,7 @@ transfer is budgeted, cancellable, and (optionally) bandwidth-paced.
 """
 from __future__ import annotations
 
+import threading
 import time
 from concurrent.futures import CancelledError
 from typing import Callable, Dict, List, Optional, Tuple
@@ -76,7 +81,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.io import IOEngine, IOPriority, IORequest
-from repro.obs.tracer import CAT_HINT
+from repro.obs.tracer import (CAT_COPY, CAT_HINT, CAT_WAIT, CAT_WORK,
+                              EXEC_TRACK, NO_SPAN)
 from repro.offload.stores import HostStore, SSDStore, TieredVector, TrafficMeter
 from repro.optim.cpu_adam import CpuAdam
 
@@ -110,6 +116,41 @@ def _xfer(meter: TrafficMeter, engine: IOEngine, category: str, route: str,
     the meter.add/throttle pair lives for non-chunked transfers."""
     meter.add(category, route, nbytes)
     engine.throttle(route, nbytes)
+
+
+def _tracing(coord):
+    """The coordinator's tracer when it is recording, else None."""
+    tr = getattr(coord, "tracer", None)
+    return tr if tr is not None and tr.enabled else None
+
+
+def _to_device(coord, arr: np.ndarray, stream: str, l: int = -1,
+               m: int = -1) -> jax.Array:
+    """Host -> device copy on the calling thread. Traced, the call is
+    one ``exec.h2d`` span: the time the caller spends in it, not the
+    end of the DMA (no sync is added to find that)."""
+    tr = _tracing(coord)
+    with tr.span(EXEC_TRACK, "exec.h2d", CAT_COPY, bytes=int(arr.nbytes),
+                 stream=stream, l=l, m=m) if tr else NO_SPAN:
+        return jnp.asarray(arr)
+
+
+def _to_host(coord, x, stream: str, l: int = -1, m: int = -1,
+             dtype=None) -> np.ndarray:
+    """Device -> host copy on the calling thread (then ``astype(dtype)``
+    when given). Traced, it is split in two spans: ``exec.device_wait``,
+    blocking until ``x`` is computed (the copy would wait for the same,
+    so this adds no sync), then ``exec.d2h``, the copy and cast."""
+    tr = _tracing(coord)
+    if tr is not None:
+        with tr.span(EXEC_TRACK, "exec.device_wait", CAT_WAIT,
+                     stream=stream, l=l, m=m):
+            jax.block_until_ready(x)
+    with tr.span(EXEC_TRACK, "exec.d2h", CAT_COPY,
+                 bytes=int(getattr(x, "nbytes", 0)), stream=stream, l=l,
+                 m=m) if tr else NO_SPAN:
+        arr = np.asarray(x)
+        return arr if dtype is None else arr.astype(dtype)
 
 
 def _cancel_or_drain(req: IORequest):
@@ -165,9 +206,14 @@ class ParameterCoordinator:
         lookahead exists to protect."""
         gate = self._gate.pop(l, None)
         self._gate_ready.pop(l, None)
+        tr = _tracing(self)
+        track = threading.current_thread().name if tr else ""
         if gate is not None:
-            gate()
-        return self.vectors[l].read()              # meters ssd->cpu
+            with tr.span(track, "param.gate", CAT_WAIT, l=l) if tr \
+                    else NO_SPAN:
+                gate()
+        with tr.span(track, "param.read", CAT_WORK, l=l) if tr else NO_SPAN:
+            return self.vectors[l].read()          # meters ssd->cpu
 
     def prefetch(self, l: int, consumer: bool = False):
         """Submit layer l's async host fetch. A HINT (``consumer=False``)
@@ -198,8 +244,12 @@ class ParameterCoordinator:
         else:
             self.la_misses += 1
             _hint_settle(self, "param", l, "late")
-        host_arr = self._futures.pop(l).result()
-        dev = jnp.asarray(host_arr)                 # "PCIe" copy
+        req = self._futures.pop(l)
+        tr = _tracing(self)
+        with tr.span(EXEC_TRACK, "exec.param_wait", CAT_WAIT, l=l) if tr \
+                else NO_SPAN:
+            host_arr = req.result()
+        dev = _to_device(self, host_arr, "param", l=l)   # "PCIe" copy
         _xfer(self.meter, self.engine, "param", "cpu->gpu", host_arr.nbytes)
         return dev
 
@@ -260,7 +310,7 @@ class InterLayerTensorCoordinator:
         """Offload layer-l input checkpoint for micro-batch m."""
         if keep_on_device:
             self._device_kept[(l, m)] = y_dev
-        arr = np.asarray(y_dev).reshape(-1)
+        arr = _to_host(self, y_dev, "ckpt", l=l, m=m).reshape(-1)
         _xfer(self.meter, self.engine, "ckpt", "gpu->cpu", arr.nbytes)
         self._shapes[("c", l, m)] = y_dev.shape
         k = int(round(self.x * arr.size))
@@ -293,7 +343,8 @@ class InterLayerTensorCoordinator:
         tail = self.host.pop(name + ":tail")   # consume CPU cache
         arr = np.concatenate([head, tail])
         _xfer(self.meter, self.engine, "ckpt", "cpu->gpu", arr.nbytes)
-        return jnp.asarray(arr).reshape(self._shapes[("c", l, m)])
+        return _to_device(self, arr, "ckpt", l=l, m=m).reshape(
+            self._shapes[("c", l, m)])
 
     def prefetch_bwd(self, l: int, m: int):
         """``PREFETCH_CKPT`` hint: start the backward tail's SSD re-read
@@ -355,7 +406,7 @@ class InterLayerTensorCoordinator:
             _hint_settle(self, "ckpt", (l, m), "unused")
             _cancel_or_drain(pre)
         _xfer(self.meter, self.engine, "ckpt", "cpu->gpu", arr.nbytes)
-        return jnp.asarray(arr).reshape(shape)
+        return _to_device(self, arr, "ckpt", l=l, m=m).reshape(shape)
 
     def wait_pending(self):
         """Drain all outstanding checkpoint spills (engine teardown)."""
@@ -417,7 +468,7 @@ class InterLayerTensorCoordinator:
         if keep_on_device:
             self._device_kept[(-l - 1, m)] = dx_dev
             return
-        arr = np.asarray(dx_dev)
+        arr = _to_host(self, dx_dev, "inter_grad", l=l, m=m)
         _xfer(self.meter, self.engine, "inter_grad", "gpu->cpu", arr.nbytes)
         self._shapes[("g", l, m)] = dx_dev.shape
         self.host.put(self._key("g", l, m), arr)
@@ -431,14 +482,15 @@ class InterLayerTensorCoordinator:
         # the gpu->cpu transfer now, and the cpu->gpu read later.
         for k in [k for k in self._device_kept if k[0] == -l - 1]:
             dx = self._device_kept.pop(k)
-            arr = np.asarray(dx)
+            arr = _to_host(self, dx, "inter_grad", l=l, m=k[1])
             _xfer(self.meter, self.engine, "inter_grad", "gpu->cpu",
                   arr.nbytes)
             self._shapes[("g", l, k[1])] = dx.shape
             self.host.put(self._key("g", l, k[1]), arr)
         arr = self.host.pop(self._key("g", l, m))
         _xfer(self.meter, self.engine, "inter_grad", "cpu->gpu", arr.nbytes)
-        return jnp.asarray(arr).reshape(self._shapes[("g", l, m)])
+        return _to_device(self, arr, "inter_grad", l=l, m=m).reshape(
+            self._shapes[("g", l, m)])
 
 
 class ActivationCoordinator:
@@ -477,7 +529,7 @@ class ActivationCoordinator:
         leaves, treedef = jax.tree.flatten(vjp)
         metas, chunks = [], []
         for leaf in leaves:
-            arr = np.asarray(leaf)
+            arr = _to_host(self, leaf, "act", l=l, m=m)
             # record the TRUE shape first: ascontiguousarray promotes
             # 0-d scalars (slice indices etc.) to (1,), and a scalar
             # restored 1-d would break the vjp's transpose rules
@@ -562,9 +614,9 @@ class ActivationCoordinator:
         leaves, off = [], 0
         for dt, shp in self._meta[key]:
             nb = int(np.prod(shp)) * dt.itemsize
-            leaves.append(jnp.asarray(
-                np.frombuffer(buf[off:off + nb].tobytes(),
-                              dtype=dt).reshape(shp)))
+            leaves.append(_to_device(
+                self, np.frombuffer(buf[off:off + nb].tobytes(),
+                                    dtype=dt).reshape(shp), "act", l=l, m=m))
             off += nb
         vjp = jax.tree.unflatten(self._tree[key], leaves)
         self._forget(key)
@@ -654,7 +706,7 @@ class KVBlockCoordinator:
         leaves, treedef = jax.tree.flatten(caches)
         metas, chunks = [], []
         for leaf in leaves:
-            arr = np.asarray(leaf)
+            arr = _to_host(self, leaf, "kv", l=l, m=r)
             metas.append((arr.dtype, arr.shape))
             chunks.append(np.ascontiguousarray(arr).reshape(-1)
                           .view(np.uint8))
@@ -743,9 +795,9 @@ class KVBlockCoordinator:
         leaves, off = [], 0
         for dt, shp in self._meta[key]:
             nb = int(np.prod(shp)) * dt.itemsize
-            leaves.append(jnp.asarray(
-                np.frombuffer(buf[off:off + nb].tobytes(),
-                              dtype=dt).reshape(shp)))
+            leaves.append(_to_device(
+                self, np.frombuffer(buf[off:off + nb].tobytes(),
+                                    dtype=dt).reshape(shp), "kv", l=l, m=r))
             off += nb
         caches = jax.tree.unflatten(self._tree[key], leaves)
         self._forget(key)
@@ -858,28 +910,40 @@ class OptimizerStepCoordinator:
     def submit_early(self, l: int, g_dev: jax.Array, step: int):
         """After layer l's backward: transfer grads, update the (1-α)
         fraction, retain grads for the α fraction (CPU-resident)."""
-        g = np.asarray(g_dev).astype(np.float32)
+        g = _to_host(self, g_dev, "grad", l=l, dtype=np.float32)
         _xfer(self.meter, self.engine, "grad", "gpu->cpu", g.nbytes)
 
         def work():
             n = self.masters[l].n
             k = self._k_early(l)
             if k > 0:
-                mast = self.masters[l].read_range(0, k)
-                m_ = self.ms[l].read_range(0, k)
-                v_ = self.vs[l].read_range(0, k)
-                self.adam.update(mast, m_, v_, g[:k], step)
-                self._write_range(self.masters[l], mast, 0, k)
-                self._write_range(self.ms[l], m_, 0, k)
-                self._write_range(self.vs[l], v_, 0, k)
-                lowp = mast.astype(self.param_dtype)
-                self._write_range(self.params[l], lowp, 0, k)
+                with self._work_span("opt.state_read", l, "early", k):
+                    mast = self.masters[l].read_range(0, k)
+                    m_ = self.ms[l].read_range(0, k)
+                    v_ = self.vs[l].read_range(0, k)
+                with self._work_span("opt.adam", l, "early", k):
+                    self.adam.update(mast, m_, v_, g[:k], step)
+                with self._work_span("opt.state_write", l, "early", k):
+                    self._write_range(self.masters[l], mast, 0, k)
+                    self._write_range(self.ms[l], m_, 0, k)
+                    self._write_range(self.vs[l], v_, 0, k)
+                    lowp = mast.astype(self.param_dtype)
+                    self._write_range(self.params[l], lowp, 0, k)
             if k < n:
                 self.host.put(f"pending_grad:{l}", g[k:].copy())
 
         self._early_futs[l] = self.engine.submit(
             work, priority=IOPriority.OPTIMIZER_STATE, category="opt",
             route="cpu->ssd", nbytes=g.nbytes)
+
+    def _work_span(self, name: str, l: int, seg: str, n: int):
+        """A worker-side span of one Adam segment (``seg`` "early" or
+        "late", ``n`` elements) on the running thread's track."""
+        tr = _tracing(self)
+        if tr is None:
+            return NO_SPAN
+        return tr.span(threading.current_thread().name, name, CAT_WORK,
+                       l=l, seg=seg, n=n)
 
     def _write_range(self, vec: TieredVector, data: np.ndarray, lo: int, hi: int):
         vec.write_seg(data, lo)
@@ -892,7 +956,10 @@ class OptimizerStepCoordinator:
         byte counters are hint-invariant either way."""
         f = self._early_futs.pop(l, None)
         if f is not None:
-            f.result()
+            tr = _tracing(self)
+            with tr.span(EXEC_TRACK, "opt.early_wait", CAT_WAIT, l=l) if tr \
+                    else NO_SPAN:
+                f.result()
         pre = self._late_pre.pop(l, None)
         n = self.masters[l].n
         k = self._k_early(l)
@@ -918,20 +985,24 @@ class OptimizerStepCoordinator:
             self.la_misses += 1
 
         def work():
-            if pre is not None:
-                # running-or-done by construction (a queued hint was
-                # cancelled above), so this wait is bounded and cannot
-                # deadlock the request workers
-                mast, m_, v_ = pre.result()
-            else:
-                mast = self.masters[l].read_range(k, n)
-                m_ = self.ms[l].read_range(k, n)
-                v_ = self.vs[l].read_range(k, n)
-            self.adam.update(mast, m_, v_, g_tail, step)
-            self._write_range(self.masters[l], mast, k, n)
-            self._write_range(self.ms[l], m_, k, n)
-            self._write_range(self.vs[l], v_, k, n)
-            self._write_range(self.params[l], mast.astype(self.params[l].dtype), k, n)
+            with self._work_span("opt.state_read", l, "late", n - k):
+                if pre is not None:
+                    # running-or-done by construction (a queued hint was
+                    # cancelled above), so this wait is bounded and
+                    # cannot deadlock the request workers
+                    mast, m_, v_ = pre.result()
+                else:
+                    mast = self.masters[l].read_range(k, n)
+                    m_ = self.ms[l].read_range(k, n)
+                    v_ = self.vs[l].read_range(k, n)
+            with self._work_span("opt.adam", l, "late", n - k):
+                self.adam.update(mast, m_, v_, g_tail, step)
+            with self._work_span("opt.state_write", l, "late", n - k):
+                self._write_range(self.masters[l], mast, k, n)
+                self._write_range(self.ms[l], m_, k, n)
+                self._write_range(self.vs[l], v_, k, n)
+                self._write_range(self.params[l],
+                                  mast.astype(self.params[l].dtype), k, n)
 
         self._late_futs[l] = self.engine.submit(
             work, priority=IOPriority.OPTIMIZER_STATE, category="opt",

@@ -54,17 +54,21 @@ accumulated into ``eng.op_seconds[op.name]``; :func:`stall_seconds`
 sums the kinds the GPU actually blocks on (the FETCH-class ops and the
 waits), which is what the bench-smoke artifact reports and CI gates,
 and ``repro.obs.stall_by_stream`` folds into per-stream attribution.
-When the engine's shared ``repro.obs.Tracer`` is enabled, the SAME
-``t_op``/``dt`` measurement that feeds ``op_seconds`` is also recorded
-as one span per executed op on the executor's track, tagged with the
+When the engine's shared ``repro.obs.Tracer`` is enabled, each executed
+op's body runs inside one ``Tracer.span`` on the executor's track (inside
+the same ``t_op`` interval that feeds ``op_seconds``), tagged with the
 full plan-op identity — op kind, layer ``l``, micro-batch ``m``, wave
 index (counted at the ``PHASE("fwd")`` flips), owning rank, and step —
-so a Chrome trace lines the op timeline up against the I/O channel
-tracks (queue-wait/transfer spans recorded by ``repro.io.engine``) and
-the coordinators' hint-lifecycle spans. Each backpressure skip
-(``hint_skips`` / ``act_skips``) additionally drops an instant event at
-the moment of the skip. Tracing off costs one flag test at plan start;
-the op loop is unchanged.
+and stamped on the JAX profiler's clock as well. Inside it the
+coordinators record leaf spans for what the op waits on: the
+host<->device copies (``exec.h2d``, ``exec.device_wait``,
+``exec.d2h``), a parameter fetch's request (``exec.param_wait``), an
+early Adam segment (``opt.early_wait``) and the I/O engine's in-flight
+budget (``io.budget_wait``). A Chrome trace lines the op timeline up
+against the I/O channel tracks (queue-wait/transfer spans recorded by
+``repro.io.engine``), the workers' gate, read and Adam spans and the
+coordinators' hint-lifecycle spans. Tracing off costs one flag test
+per op; the op loop's measurement is unchanged.
 
 Fault discipline: a mid-plan exception (a failed chunk op surfacing
 through a coordinator) must not leak device slots or host buffers into
@@ -88,12 +92,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.plan import Op, Plan
-from repro.obs.tracer import CAT_HINT, CAT_PLAN
-from repro.offload.coordinators import _xfer
-
-#: the executor's Chrome-trace track name (one executor thread drives
-#: all ranks; per-op rank identity rides in the span args)
-EXEC_TRACK = "exec"
+from repro.obs.tracer import CAT_PLAN, EXEC_TRACK, NO_SPAN
+from repro.offload.coordinators import _to_device, _to_host, _xfer
 
 
 def _ranks(eng):
@@ -167,12 +167,6 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
     tracer = getattr(eng, "tracer", None)
     rec = tracer is not None and tracer.enabled
     wave = -1                       # becomes 0 at the first PHASE("fwd")
-
-    def skip_evt(kind: str, op):
-        """Instant event marking one backpressure skip (hint or spill)."""
-        if rec:
-            tracer.instant(EXEC_TRACK, f"skip:{kind}", CAT_HINT,
-                           op=op.op.name, l=op.l, m=op.m)
     regs = {}                       # transient device tensors
     p_dev = None                    # current layer's params
     gacc = None                     # f32 layer-gradient accumulator
@@ -199,227 +193,219 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
         for op in plan.ops:
             k = op.op
             t_op = time.perf_counter()
-            if k is Op.FETCH_CKPT:
-                regs[("x", op.m)] = \
-                    rank_of(op.m).ckpt_c.get_ckpt_fwd(op.l, op.m)
-            elif k is Op.FWD:
-                x_in = regs.pop(("x", op.m))
-                if spill:
-                    # materialise the vjp residuals for the act stream
-                    y, res = eng.j_layer_fwd_res(p_dev, x_in)
-                    regs[("y", op.m)] = y
-                    regs[("res", op.m)] = res
-                else:
-                    regs[("y", op.m)] = eng.j_layer_fwd(p_dev, x_in)
-            elif k is Op.SPILL_ACT:
-                res = regs.pop(("res", op.m))
-                rk = rank_of(op.m)
-                if act_adaptive and _saturated(rk.ioe, bp, "cpu->ssd"):
-                    # SSDTrain's adaptive knob per (layer, micro-batch):
-                    # the write queue is saturated, so streaming this
-                    # residual would lengthen the critical path — drop
-                    # it and let FETCH_ACT degrade this micro-batch to
-                    # the recompute path (bitwise-identical results)
-                    eng.act_skips += 1
-                    skip_evt("act_spill", op)
-                    del res
-                else:
+            if k is Op.PHASE and op.tag == "fwd":
+                wave += 1
+            span = tracer.span(
+                EXEC_TRACK, k.name, CAT_PLAN, l=op.l, m=op.m, wave=wave,
+                rank=(op.m // Mr if multi and op.m >= 0 else 0),
+                step=step) if rec else NO_SPAN
+            with span:
+                if k is Op.FETCH_CKPT:
+                    regs[("x", op.m)] = \
+                        rank_of(op.m).ckpt_c.get_ckpt_fwd(op.l, op.m)
+                elif k is Op.FWD:
+                    x_in = regs.pop(("x", op.m))
+                    if spill:
+                        # materialise the vjp residuals for the act stream
+                        y, res = eng.j_layer_fwd_res(p_dev, x_in)
+                        regs[("y", op.m)] = y
+                        regs[("res", op.m)] = res
+                    else:
+                        regs[("y", op.m)] = eng.j_layer_fwd(p_dev, x_in)
+                elif k is Op.SPILL_ACT:
+                    res = regs.pop(("res", op.m))
+                    rk = rank_of(op.m)
+                    if act_adaptive and _saturated(rk.ioe, bp, "cpu->ssd"):
+                        # SSDTrain's adaptive knob per (layer, micro-batch):
+                        # the write queue is saturated, so streaming this
+                        # residual would lengthen the critical path — drop
+                        # it and let FETCH_ACT degrade this micro-batch to
+                        # the recompute path (bitwise-identical results)
+                        eng.act_skips += 1
+                        del res
+                    else:
+                        try:
+                            rk.act_c.put(op.l, op.m, res)
+                        except Exception:
+                            # a failed spill degrades this micro-batch to
+                            # the recompute path (its checkpoint tier is
+                            # intact); drop whatever the coordinator
+                            # half-tracked — the FETCH_ACT for this key
+                            # then finds nothing and counts the fallback
+                            rk.act_c.drop(op.l, op.m)
+                elif k is Op.PREFETCH_ACT:
+                    rk = rank_of(op.m)
+                    if _saturated(rk.ioe, bp, "ssd->cpu"):
+                        eng.hint_skips += 1
+                    else:
+                        rk.act_c.prefetch(op.l, op.m)
+                elif k is Op.PREFETCH_CKPT:
+                    rk = rank_of(op.m)
+                    if _saturated(rk.ioe, bp, "ssd->cpu"):
+                        eng.hint_skips += 1
+                    else:
+                        rk.ckpt_c.prefetch_bwd(op.l, op.m)
+                elif k is Op.PREFETCH_OPT:
+                    if ocfg.alpha > 0:
+                        for rk in ranks:
+                            if _saturated(rk.ioe, bp, "ssd->cpu"):
+                                eng.hint_skips += 1
+                            else:
+                                rk.opt_c.prefetch_late(op.l)
+                elif k is Op.FETCH_ACT:
+                    rk = rank_of(op.m)
                     try:
-                        rk.act_c.put(op.l, op.m, res)
+                        regs[("res", op.m)] = rk.act_c.get(op.l, op.m)
                     except Exception:
-                        # a failed spill degrades this micro-batch to
-                        # the recompute path (its checkpoint tier is
-                        # intact); drop whatever the coordinator
-                        # half-tracked — the FETCH_ACT for this key
-                        # then finds nothing and counts the fallback
+                        # failed (or never-landed) fetch: fall back to the
+                        # checkpoint re-read; BWD recomputes the residuals
                         rk.act_c.drop(op.l, op.m)
-            elif k is Op.PREFETCH_ACT:
-                rk = rank_of(op.m)
-                if _saturated(rk.ioe, bp, "ssd->cpu"):
-                    eng.hint_skips += 1
-                    skip_evt("hint", op)
-                else:
-                    rk.act_c.prefetch(op.l, op.m)
-            elif k is Op.PREFETCH_CKPT:
-                rk = rank_of(op.m)
-                if _saturated(rk.ioe, bp, "ssd->cpu"):
-                    eng.hint_skips += 1
-                    skip_evt("hint", op)
-                else:
-                    rk.ckpt_c.prefetch_bwd(op.l, op.m)
-            elif k is Op.PREFETCH_OPT:
-                if ocfg.alpha > 0:
+                        eng.act_fallbacks += 1
+                        regs[("x", op.m)] = \
+                            rk.ckpt_c.get_ckpt_bwd(op.l, op.m)
+                elif k is Op.SPILL_CKPT:
+                    rank_of(op.m).ckpt_c.put_ckpt(op.l, op.m,
+                                                  regs.pop(("y", op.m)),
+                                                  keep_on_device=op.keep)
+                elif k is Op.FETCH_CKPT_BWD:
+                    regs[("x", op.m)] = \
+                        rank_of(op.m).ckpt_c.get_ckpt_bwd(op.l, op.m)
+                elif k is Op.FETCH_GRAD:
+                    regs[("dy", op.m)] = \
+                        rank_of(op.m).ckpt_c.get_grad(op.l, op.m)
+                elif k is Op.BWD:
+                    # Both policies run backward from vjp residuals — spill
+                    # restores them from the act stream, recompute re-runs
+                    # the residual-returning forward on the fetched ckpt —
+                    # so spill/recompute gradients are bitwise-identical.
+                    res = regs.pop(("res", op.m), None)
+                    if res is None:
+                        _, res = eng.j_layer_fwd_res(p_dev,
+                                                     regs.pop(("x", op.m)))
+                    dx, dp = eng.j_layer_bwd_res(res, regs.pop(("dy", op.m)))
+                    if op.acc:
+                        gacc = gacc + dp
+                    else:
+                        per_mb_dp[op.m] = dp
+                    regs[("dx", op.m)] = dx
+                elif k is Op.SPILL_GRAD:
+                    rank_of(op.m).ckpt_c.put_grad(op.l, op.m,
+                                                  regs.pop(("dx", op.m)),
+                                                  keep_on_device=op.keep)
+                elif k is Op.DROP_CKPT:
+                    rank_of(op.m).ckpt_c.drop_ckpt(op.l, op.m)
+                elif k is Op.PREFETCH:
                     for rk in ranks:
                         if _saturated(rk.ioe, bp, "ssd->cpu"):
                             eng.hint_skips += 1
-                            skip_evt("hint", op)
                         else:
-                            rk.opt_c.prefetch_late(op.l)
-            elif k is Op.FETCH_ACT:
-                rk = rank_of(op.m)
-                try:
-                    regs[("res", op.m)] = rk.act_c.get(op.l, op.m)
-                except Exception:
-                    # failed (or never-landed) fetch: fall back to the
-                    # checkpoint re-read; BWD recomputes the residuals
-                    rk.act_c.drop(op.l, op.m)
-                    eng.act_fallbacks += 1
-                    regs[("x", op.m)] = \
-                        rk.ckpt_c.get_ckpt_bwd(op.l, op.m)
-            elif k is Op.SPILL_CKPT:
-                rank_of(op.m).ckpt_c.put_ckpt(op.l, op.m,
-                                              regs.pop(("y", op.m)),
-                                              keep_on_device=op.keep)
-            elif k is Op.FETCH_CKPT_BWD:
-                regs[("x", op.m)] = \
-                    rank_of(op.m).ckpt_c.get_ckpt_bwd(op.l, op.m)
-            elif k is Op.FETCH_GRAD:
-                regs[("dy", op.m)] = \
-                    rank_of(op.m).ckpt_c.get_grad(op.l, op.m)
-            elif k is Op.BWD:
-                # Both policies run backward from vjp residuals — spill
-                # restores them from the act stream, recompute re-runs
-                # the residual-returning forward on the fetched ckpt —
-                # so spill/recompute gradients are bitwise-identical.
-                res = regs.pop(("res", op.m), None)
-                if res is None:
-                    _, res = eng.j_layer_fwd_res(p_dev,
-                                                 regs.pop(("x", op.m)))
-                dx, dp = eng.j_layer_bwd_res(res, regs.pop(("dy", op.m)))
-                if op.acc:
-                    gacc = gacc + dp
-                else:
-                    per_mb_dp[op.m] = dp
-                regs[("dx", op.m)] = dx
-            elif k is Op.SPILL_GRAD:
-                rank_of(op.m).ckpt_c.put_grad(op.l, op.m,
-                                              regs.pop(("dx", op.m)),
-                                              keep_on_device=op.keep)
-            elif k is Op.DROP_CKPT:
-                rank_of(op.m).ckpt_c.drop_ckpt(op.l, op.m)
-            elif k is Op.PREFETCH:
-                for rk in ranks:
-                    if _saturated(rk.ioe, bp, "ssd->cpu"):
-                        eng.hint_skips += 1
-                        skip_evt("hint", op)
-                    else:
-                        rk.params_c.prefetch(op.l)
-            elif k is Op.FETCH_PARAM:
-                p_dev = ranks[0].params_c.get(op.l)
-            elif k is Op.ALLGATHER:
-                p_dev = eng._allgather_params(op.l)
-            elif k is Op.RELEASE_PARAM:
-                p_dev = None
-            elif k is Op.RESET_PARAMS:
-                for rk in ranks:
-                    rk.params_c.reset()
-            elif k is Op.EMBED_FWD:
-                regs[("y", op.m)] = eng.j_embed(eng.embed,
-                                                jnp.asarray(mbs[op.m]))
-            elif k is Op.HEAD_BWD:
-                lab, w = eng._labels(mbs[op.m])
-                loss, du, dn, dx = eng.j_head_bwd(
-                    eng.unembed, eng.final_norm, regs.pop(("x", op.m)),
-                    lab, w, denom)
-                if op.acc:
-                    loss_total += float(loss)
-                    d_un = d_un + du
-                    d_nm = d_nm + dn
-                else:
-                    head_stash[op.m] = (loss, du, dn)
-                regs[("dx", op.m)] = dx
-            elif k is Op.EMBED_BWD:
-                d = eng.j_embed_bwd(eng.embed, jnp.asarray(mbs[op.m]),
-                                    regs.pop(("dy", op.m)))
-                if op.acc:
-                    d_embed = d_embed + d
-                else:
-                    embed_stash[op.m] = d
-            elif k is Op.GRAD_INIT:
-                gacc = jnp.zeros((eng.P,), jnp.float32)
-            elif k is Op.GRAD_SPILL:
-                rk = ranks[0]
-                g = np.asarray(gacc)
-                _xfer(rk.meter, rk.ioe, "grad", "gpu->cpu", g.nbytes)
-                rk.host.put(f"gacc:{op.l}", g)
-                gacc = None
-            elif k is Op.GRAD_FETCH_ACC:
-                rk = ranks[0]
-                g_host = rk.host.pop(f"gacc:{op.l}")
-                _xfer(rk.meter, rk.ioe, "grad", "cpu->gpu", g_host.nbytes)
-                gacc = gacc + jnp.asarray(g_host)
-            elif k is Op.WRITEBACK_GRAD:
-                ranks[0].opt_c.submit_early(op.l, gacc, step)
-                gacc = None
-            elif k is Op.REDUCE_SCATTER:
-                eng._reduce_scatter_update(op.l, per_mb_dp, step)
-                per_mb_dp = {}
-            elif k is Op.OPT_LATE:
-                # epilogue seam (default): flush THIS step's α-tail now
-                # (it was retained at WRITEBACK_GRAD) and re-arm the
-                # gate, so the flush overlaps the next step's first
-                # fetches. A tag="pro" op is the lookahead-off PROLOGUE
-                # variant: flush the PREVIOUS step's tail at plan start
-                # (same (gradient, Adam-step) pairs => bitwise-equal).
-                pro = op.tag == "pro"
-                if ocfg.alpha > 0 and not (pro and step <= 1):
+                            rk.params_c.prefetch(op.l)
+                elif k is Op.FETCH_PARAM:
+                    p_dev = ranks[0].params_c.get(op.l)
+                elif k is Op.ALLGATHER:
+                    p_dev = eng._allgather_params(op.l)
+                elif k is Op.RELEASE_PARAM:
+                    p_dev = None
+                elif k is Op.RESET_PARAMS:
                     for rk in ranks:
-                        rk.opt_c.flush_late(op.l, step - 1 if pro
-                                            else step)
-                        # the ready probe keeps a hinted fetch from
-                        # parking a request worker on a still-QUEUED
-                        # flush (deadlock guard for deep lookahead)
-                        rk.params_c.set_gate(
-                            op.l,
-                            (lambda c, ll: lambda: c.wait_late(ll))(
-                                rk.opt_c, op.l),
-                            (lambda c, ll: lambda: c.late_settled(ll))(
-                                rk.opt_c, op.l))
-            elif k is Op.FOLD_HEAD:
-                for m in op.ms:
-                    loss, du, dn = head_stash[m]
-                    loss_total += float(loss)
-                    d_un = d_un + du
-                    d_nm = d_nm + dn
-                head_stash = {}
-            elif k is Op.FOLD_EMBED:
-                for m in op.ms:
-                    d_embed = d_embed + embed_stash[m]
-                embed_stash = {}
-            elif k is Op.ALLREDUCE_HEAD:
-                head_bytes = int(d_embed.nbytes + d_un.nbytes
-                                 + d_nm.nbytes)
-                ring = 2 * (eng.R - 1) * head_bytes // eng.R
-                eng._collective("head_grad", ring, ring)
-            elif k is Op.HEAD_ADAM:
-                for name, g in (("embed", d_embed), ("unembed", d_un),
-                                ("final_norm", d_nm)):
-                    st = eng.head_state[name]
-                    p2, st["m"], st["v"] = eng.j_adam_dev(
-                        getattr(eng, name), st["m"], st["v"], g,
-                        jnp.asarray(step, jnp.int32),
-                        jnp.asarray(ocfg.lr))
-                    setattr(eng, name, p2)
-            elif k is Op.WAIT_OPT:
-                for rk in ranks:
-                    rk.opt_c.wait_all()
-            elif k is Op.BARRIER:
-                jax.effects_barrier()
-            elif k is Op.PHASE:
-                if op.tag == "fwd":
-                    wave += 1
-                flip(op.tag)
-            else:                    # pragma: no cover - compiler bug
-                raise ValueError(f"unknown plan op {op!r}")
-            dt = time.perf_counter() - t_op
-            op_seconds[k.name] += dt
-            if rec:
-                # the SAME measurement op_seconds accumulates, as a span
-                tracer.record(
-                    EXEC_TRACK, k.name, CAT_PLAN, t_op, t_op + dt,
-                    l=op.l, m=op.m, wave=wave,
-                    rank=(op.m // Mr if multi and op.m >= 0 else 0),
-                    step=step)
+                        rk.params_c.reset()
+                elif k is Op.EMBED_FWD:
+                    regs[("y", op.m)] = eng.j_embed(eng.embed,
+                                                    jnp.asarray(mbs[op.m]))
+                elif k is Op.HEAD_BWD:
+                    lab, w = eng._labels(mbs[op.m])
+                    loss, du, dn, dx = eng.j_head_bwd(
+                        eng.unembed, eng.final_norm, regs.pop(("x", op.m)),
+                        lab, w, denom)
+                    if op.acc:
+                        loss_total += float(loss)
+                        d_un = d_un + du
+                        d_nm = d_nm + dn
+                    else:
+                        head_stash[op.m] = (loss, du, dn)
+                    regs[("dx", op.m)] = dx
+                elif k is Op.EMBED_BWD:
+                    d = eng.j_embed_bwd(eng.embed, jnp.asarray(mbs[op.m]),
+                                        regs.pop(("dy", op.m)))
+                    if op.acc:
+                        d_embed = d_embed + d
+                    else:
+                        embed_stash[op.m] = d
+                elif k is Op.GRAD_INIT:
+                    gacc = jnp.zeros((eng.P,), jnp.float32)
+                elif k is Op.GRAD_SPILL:
+                    rk = ranks[0]
+                    g = _to_host(eng, gacc, "grad", l=op.l)
+                    _xfer(rk.meter, rk.ioe, "grad", "gpu->cpu", g.nbytes)
+                    rk.host.put(f"gacc:{op.l}", g)
+                    gacc = None
+                elif k is Op.GRAD_FETCH_ACC:
+                    rk = ranks[0]
+                    g_host = rk.host.pop(f"gacc:{op.l}")
+                    _xfer(rk.meter, rk.ioe, "grad", "cpu->gpu", g_host.nbytes)
+                    gacc = gacc + _to_device(eng, g_host, "grad", l=op.l)
+                elif k is Op.WRITEBACK_GRAD:
+                    ranks[0].opt_c.submit_early(op.l, gacc, step)
+                    gacc = None
+                elif k is Op.REDUCE_SCATTER:
+                    eng._reduce_scatter_update(op.l, per_mb_dp, step)
+                    per_mb_dp = {}
+                elif k is Op.OPT_LATE:
+                    # epilogue seam (default): flush THIS step's α-tail now
+                    # (it was retained at WRITEBACK_GRAD) and re-arm the
+                    # gate, so the flush overlaps the next step's first
+                    # fetches. A tag="pro" op is the lookahead-off PROLOGUE
+                    # variant: flush the PREVIOUS step's tail at plan start
+                    # (same (gradient, Adam-step) pairs => bitwise-equal).
+                    pro = op.tag == "pro"
+                    if ocfg.alpha > 0 and not (pro and step <= 1):
+                        for rk in ranks:
+                            rk.opt_c.flush_late(op.l, step - 1 if pro
+                                                else step)
+                            # the ready probe keeps a hinted fetch from
+                            # parking a request worker on a still-QUEUED
+                            # flush (deadlock guard for deep lookahead)
+                            rk.params_c.set_gate(
+                                op.l,
+                                (lambda c, ll: lambda: c.wait_late(ll))(
+                                    rk.opt_c, op.l),
+                                (lambda c, ll: lambda: c.late_settled(ll))(
+                                    rk.opt_c, op.l))
+                elif k is Op.FOLD_HEAD:
+                    for m in op.ms:
+                        loss, du, dn = head_stash[m]
+                        loss_total += float(loss)
+                        d_un = d_un + du
+                        d_nm = d_nm + dn
+                    head_stash = {}
+                elif k is Op.FOLD_EMBED:
+                    for m in op.ms:
+                        d_embed = d_embed + embed_stash[m]
+                    embed_stash = {}
+                elif k is Op.ALLREDUCE_HEAD:
+                    head_bytes = int(d_embed.nbytes + d_un.nbytes
+                                     + d_nm.nbytes)
+                    ring = 2 * (eng.R - 1) * head_bytes // eng.R
+                    eng._collective("head_grad", ring, ring)
+                elif k is Op.HEAD_ADAM:
+                    for name, g in (("embed", d_embed), ("unembed", d_un),
+                                    ("final_norm", d_nm)):
+                        st = eng.head_state[name]
+                        p2, st["m"], st["v"] = eng.j_adam_dev(
+                            getattr(eng, name), st["m"], st["v"], g,
+                            jnp.asarray(step, jnp.int32),
+                            jnp.asarray(ocfg.lr))
+                        setattr(eng, name, p2)
+                elif k is Op.WAIT_OPT:
+                    for rk in ranks:
+                        rk.opt_c.wait_all()
+                elif k is Op.BARRIER:
+                    jax.effects_barrier()
+                elif k is Op.PHASE:
+                    flip(op.tag)
+                else:                    # pragma: no cover - compiler bug
+                    raise ValueError(f"unknown plan op {op!r}")
+            op_seconds[k.name] += time.perf_counter() - t_op
         flip(None)
     except BaseException:
         # Mid-plan failure: free the device slots and cancel in-flight
