@@ -24,6 +24,8 @@ apart. The run's own output is unchanged; one more line, ``SPLIT
 * ``clock_check``: the largest distance, in ms, between an executor op
   span in the profiler's trace and the same span mapped from the ring
   through the ``bench.sync`` annotation;
+* ``adam_threads``: how many ``opt.adam`` spans ran on each thread
+  count, per segment (``"early 8": 8``);
 * ``tokens_per_s`` of the traced window, and ``dropped`` spans.
 
 It needs the chip, as ``run.py`` does.
@@ -204,6 +206,16 @@ def split(trace: dict, spans, sync_host_s: float, steps: int) -> dict:
             "clock_check": {"max_ms": worst, "spans": pairs}}
 
 
+def adam_threads(spans) -> dict:
+    """``opt.adam`` spans counted by segment and thread count."""
+    out: dict = {}
+    for _track, name, _cat, _t0, _t1, a in spans:
+        if name == "opt.adam":
+            key = f"{a.get('seg')} {a.get('threads', '?')}"
+            out[key] = out.get(key, 0) + 1
+    return dict(sorted(out.items()))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -232,6 +244,7 @@ def main(argv=None) -> int:
            "dropped": _seen["dropped"],
            "per_step": {k: v / steps for k, v in
                         sorted(_seen["totals"].items())},
+           "adam_threads": adam_threads(_seen["spans"]),
            **split(_seen["trace"], _seen["spans"], _seen["sync"], steps)}
     line = json.dumps(out)
     print("SPLIT " + line, file=sys.stderr, flush=True)

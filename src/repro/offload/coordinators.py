@@ -921,7 +921,8 @@ class OptimizerStepCoordinator:
                     mast = self.masters[l].read_range(0, k)
                     m_ = self.ms[l].read_range(0, k)
                     v_ = self.vs[l].read_range(0, k)
-                with self._work_span("opt.adam", l, "early", k):
+                with self._work_span("opt.adam", l, "early", k,
+                                     threads=self.adam.threads_for(k)):
                     self.adam.update(mast, m_, v_, g[:k], step)
                 with self._work_span("opt.state_write", l, "early", k):
                     self._write_range(self.masters[l], mast, 0, k)
@@ -936,14 +937,15 @@ class OptimizerStepCoordinator:
             work, priority=IOPriority.OPTIMIZER_STATE, category="opt",
             route="cpu->ssd", nbytes=g.nbytes)
 
-    def _work_span(self, name: str, l: int, seg: str, n: int):
+    def _work_span(self, name: str, l: int, seg: str, n: int, **args):
         """A worker-side span of one Adam segment (``seg`` "early" or
-        "late", ``n`` elements) on the running thread's track."""
+        "late", ``n`` elements) on the running thread's track; ``args``
+        (``opt.adam``'s ``threads``) go on the span as well."""
         tr = _tracing(self)
         if tr is None:
             return NO_SPAN
         return tr.span(threading.current_thread().name, name, CAT_WORK,
-                       l=l, seg=seg, n=n)
+                       l=l, seg=seg, n=n, **args)
 
     def _write_range(self, vec: TieredVector, data: np.ndarray, lo: int, hi: int):
         vec.write_seg(data, lo)
@@ -995,7 +997,8 @@ class OptimizerStepCoordinator:
                     mast = self.masters[l].read_range(k, n)
                     m_ = self.ms[l].read_range(k, n)
                     v_ = self.vs[l].read_range(k, n)
-            with self._work_span("opt.adam", l, "late", n - k):
+            with self._work_span("opt.adam", l, "late", n - k,
+                                 threads=self.adam.threads_for(n - k)):
                 self.adam.update(mast, m_, v_, g_tail, step)
             with self._work_span("opt.state_write", l, "late", n - k):
                 self._write_range(self.masters[l], mast, k, n)
