@@ -33,24 +33,6 @@ B1 = reference.B1
 WINDOW_POOL = 8
 
 
-def arch_config(c: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
-    from repro.configs.base import ArchConfig
-    cfg = ArchConfig(
-        name=c["name"], family="dense", source=c["source"],
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        act={"gelu_tanh": "gelu", "swiglu": "swiglu"}[c["mlp"]])
-    if cfg.padded_vocab != c["table_rows"]:
-        raise ValueError(f"{c['name']}: the program's table has "
-                         f"{cfg.padded_vocab} rows, the file says "
-                         f"{c['table_rows']}")
-    return cfg
-
-
 def offload_config(cell: dict, cfg_file: dict):
     from repro.core.perfmodel import StorageRatios
     from repro.offload import OffloadConfig
@@ -69,9 +51,10 @@ def seed_key(seed: int) -> jax.Array:
     return jnp.asarray(np.array([s >> 32, s & 0xFFFFFFFF], np.uint32))
 
 
-def _segments(arch) -> List[tuple]:
+def _segments(leaves) -> List[tuple]:
+    """(name, start, end) of each leaf in a layer's flat vector."""
     out, off = [], 0
-    for name, shape in reference.layer_leaves(arch):
+    for name, shape in leaves:
         n = int(np.prod(shape))
         out.append((name, off, off + n))
         off += n
@@ -93,9 +76,12 @@ def _step(eng, tokens) -> float:
 class ProgramRun:
     """One engine, its set-up steps, its window and what they left."""
 
-    def __init__(self, cfg_file: dict, cell: dict, seed: int, workdir: Path):
-        self.arch = reference.Arch.from_config(cfg_file)
-        self.cfg = arch_config(cfg_file)
+    def __init__(self, model, cfg_file: dict, cell: dict, seed: int,
+                 workdir: Path):
+        a = model.Arch.from_config(cfg_file)
+        self.segments = [_segments(model.layer_leaves(a, l))
+                         for l in range(a.layers)]
+        self.cfg = model.arch_config(cfg_file)
         self.ocfg = offload_config(cell, cfg_file)
         self.cell = cell
         self.batches = traffic_gen.batches(
@@ -129,9 +115,8 @@ class ProgramRun:
 
     def _grad_norms(self) -> Dict[str, float]:
         out = {}
-        segs = _segments(self.arch)
         for l, m in enumerate(self._layer_vectors(self.eng.m_m)):
-            for name, lo, hi in segs:
+            for name, lo, hi in self.segments[l]:
                 out[reference.leaf_name(l, name)] = _norm(m[lo:hi]) / (1 - B1)
         for t in reference.HEAD_LEAVES:
             m = self.eng.head_state[t]["m"]
@@ -142,12 +127,14 @@ class ProgramRun:
     def setup(self, log=lambda msg: None) -> None:
         """Build the engine and drive it through the set-up steps."""
         from repro.offload import make_engine
-        assert sum(hi - lo for _, lo, hi in _segments(self.arch)) \
-            == self.cfg.layer_params(0)
         t = time.perf_counter()
         self.eng = eng = make_engine(self.cfg, self.ocfg, self.key,
                                      str(self.workdir))
-        assert eng.P == self.cfg.layer_params(0), (eng.P, "layer size")
+        sizes = [v.n for v in eng.p_vecs]
+        declared = [segs[-1][2] for segs in self.segments]
+        if sizes != declared:
+            raise ValueError(f"the program's layer vectors hold {sizes} "
+                             f"elements, the model's leaves {declared}")
         log(f"set-up: make_engine {time.perf_counter() - t!r} s")
         t = time.perf_counter()
         self._master0 = self._layer_vectors(eng.m_master)
@@ -165,11 +152,10 @@ class ProgramRun:
                     f"{time.perf_counter() - t!r} s")
 
     def _change_norms(self) -> Dict[str, float]:
-        segs = _segments(self.arch)
         change = {}
         for l, m in enumerate(self._layer_vectors(self.eng.m_master)):
             m0 = self._master0[l]
-            for name, lo, hi in segs:
+            for name, lo, hi in self.segments[l]:
                 change[reference.leaf_name(l, name)] = _norm(
                     m[lo:hi] - m0[lo:hi])
         for t, now in self._head().items():

@@ -25,6 +25,7 @@ import argparse
 import json
 import sys
 from pathlib import Path
+from typing import Optional
 
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
@@ -38,17 +39,20 @@ import traffic_gen  # noqa: E402
 VARIANTS = {"control": {"mode": "control"}, "half_batch": {"half_batch": True}}
 
 
-def readings(cfg_file: dict, cell: dict, seed: int) -> dict:
-    """{variant: compare.numbers(variant, f32 reference)} for one seed."""
+def readings(cfg_file: dict, cell: dict, seed: int,
+             reg: Optional[harness.Registry] = None) -> dict:
+    """{variant: compare.numbers(variant, f32 reference)} for one seed,
+    with the model module the configuration names."""
     import program
+    model = (reg or harness.Registry()).model_of(cfg_file)
     batches = traffic_gen.batches(cell, cfg_file["vocab_size"], seed,
                                   cell["setup_steps"] + 1)
-    arch = reference.Arch.from_config(cfg_file)
+    arch = model.Arch.from_config(cfg_file)
     key = program.seed_key(seed)
 
     def follow(**kw):
-        return reference.run(arch, key, cell["lr"], cell["micro_batches"],
-                             batches, **kw)
+        return reference.run(model, arch, key, cell["lr"],
+                             cell["micro_batches"], batches, **kw)
     ref = follow()
     return {name: compare.numbers(follow(**kw), ref)
             for name, kw in VARIANTS.items()}
@@ -67,7 +71,7 @@ def main() -> int:
     enable_compile_cache()
     dev = jax.devices()[0]
     for seed in args.seeds:
-        out = readings(cfg_file, cell, seed)
+        out = readings(cfg_file, cell, seed, reg)
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "device": dev.device_kind, **out}), flush=True)
     return 0

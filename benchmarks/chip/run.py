@@ -63,12 +63,12 @@ def main(argv=None, reg: harness.Registry = None) -> int:
     reg = reg or harness.Registry()
     cell = reg.cell(args.workload)
     cfg_file = reg.config(cell["config"])
+    model = reg.model_of(cfg_file)
     dev = require_chip(cell["chips"])
     peaks = reg.peaks(dev.device_kind)
 
     import jax
     import compare
-    import flops
     import program
     import reference
     import trace_reduce
@@ -85,7 +85,7 @@ def main(argv=None, reg: harness.Registry = None) -> int:
     profile_dir = HERE / ".trace" / args.workload if args.trace else None
     if profile_dir is not None:
         shutil.rmtree(profile_dir, ignore_errors=True)
-    run = program.ProgramRun(cfg_file, cell, args.seed, scratch)
+    run = program.ProgramRun(model, cfg_file, cell, args.seed, scratch)
     try:
         run.setup(log)
         log(f"set-up losses {run.losses!r}; compiles so far {clock.compiles}")
@@ -132,7 +132,7 @@ def main(argv=None, reg: harness.Registry = None) -> int:
         log(f"trace: {json.dumps(trace)}")
 
     t_ref = time.perf_counter()
-    ref = reference.run(reference.Arch.from_config(cfg_file),
+    ref = reference.run(model, model.Arch.from_config(cfg_file),
                         program.seed_key(args.seed), cell["lr"],
                         cell["micro_batches"], batches)
     log(f"reference: {time.perf_counter() - t_ref!r} s over "
@@ -151,7 +151,7 @@ def main(argv=None, reg: harness.Registry = None) -> int:
         "tokens_per_step": cell["micro_batches"] * cell["micro_batch"]
         * cell["seq_len"],
         "setup_s": setup_s,
-        "flops_per_token": flops.flops_per_token(cfg_file, cell["seq_len"]),
+        "flops_per_token": model.flops_per_token(cfg_file, cell["seq_len"]),
         "peaks": peaks,
         "chips": cell["chips"],
         "device": {"platform": dev.platform, "kind": dev.device_kind,

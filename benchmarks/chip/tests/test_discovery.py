@@ -1,6 +1,6 @@
-"""Everything of a cell is found by name: adding a configuration, a
-traffic mix, a cell or a metric is adding files and entries, with no
-edit to the harness."""
+"""Everything of a cell is found by name: adding a configuration, a model
+family, a traffic mix, a cell or a metric is adding files and entries,
+with no edit to the harness."""
 import json
 
 import pytest
@@ -15,6 +15,12 @@ def test_the_committed_benchmark_resolves():
         cell = reg.cell(w["name"])
         cfg = reg.config(cell["config"])
         assert cfg["name"] == cell["config"]
+        model = reg.model_of(cfg)
+        a = model.Arch.from_config(cfg)
+        assert model.flops_per_token(cfg, cell["seq_len"]) > 0
+        for l in range(a.layers):
+            assert model.layer_leaves(a, l)
+            hash(model.layer_kind(a, l))
         assert set(cell["limits"]) >= {"loss_gap", "grad_gap",
                                        "change_gap", "bytes_mismatch"}
         for trace in (False, True):
@@ -60,6 +66,9 @@ def test_missing_names_are_errors(tmp_path):
         reg.config("no-such-config")
     with pytest.raises(LookupError):
         reg.reader("no_such_metric")
+    with pytest.raises(LookupError):
+        reg.model("no_such_family")
+    assert reg.model_of({}) is reg.model("dense")
     with pytest.raises(LookupError):
         reg.peaks("TPU v9 imaginary")
 

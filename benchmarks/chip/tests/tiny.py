@@ -32,7 +32,9 @@ def make(root: Path, configs=(("tiny", CONFIG),), limits=LIMITS,
     root = Path(root)
     for sub in ("configs", "traffic", "workloads"):
         (root / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(CHIP / "metrics", root / "metrics", dirs_exist_ok=True)
+    for sub in ("metrics", "models"):
+        shutil.copytree(CHIP / sub, root / sub, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     peaks = json.loads((CHIP / "peaks.json").read_text())
     peaks["devices"]["cpu"] = peaks["devices"]["TPU v5 lite"]
     (root / "peaks.json").write_text(json.dumps(peaks))
