@@ -103,7 +103,7 @@ def host_lines(profile_dir: str, names) -> dict:
 
 def _label(ev) -> str:
     a = ev[3]
-    return ev[0] + "".join(f" {k}={a[k]}" for k in ("l", "m", "seg")
+    return ev[0] + "".join(f" {k}={a[k]}" for k in ("l", "kind", "m", "seg")
                            if k in a and a[k] != -1)
 
 
@@ -149,7 +149,13 @@ def split(trace: dict, spans, sync_host_s: float, steps: int) -> dict:
                         + (lf[2] - lf[1])
         under = sum(_covered([(lf[1], lf[2]) for lf in leaves], e[1], e[2])
                     for e in evs)
+        by_kind = {}
+        for e in evs:
+            if "kind" in e[3]:
+                by_kind[e[3]["kind"]] = by_kind.get(e[3]["kind"], 0.0) \
+                    + (e[2] - e[1]) / 1e9 / steps
         ops[op] = {"s_per_step": total / 1e9 / steps,
+                   "kind_s_per_step": by_kind,
                    "leaves_s_per_step": {k: v / 1e9 / steps
                                          for k, v in by_leaf.items()},
                    "under_leaf_pct": 100.0 * under / total if total else None}

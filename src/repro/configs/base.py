@@ -60,6 +60,14 @@ class ArchConfig:
 
     # --- attention details ---
     rope_theta: float = 10_000.0
+    # YaRN context extension (arXiv:2309.00071) of the rotary: a factor
+    # above 1 switches it on (DeepSeek-V2's DeepseekV2YarnRotaryEmbedding)
+    rope_scaling_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     use_qk_norm: bool = False
     sliding_window: Optional[int] = None   # window for "local" layers
     global_attn_every: Optional[int] = None  # e.g. 6 => 5 local : 1 global
@@ -81,6 +89,13 @@ class ArchConfig:
     first_dense_layers: int = 0   # leading layers that use a dense MLP
     moe_every: int = 1            # MoE in layers where i % moe_every == moe_offset
     moe_offset: int = 0
+    moe_norm_topk: bool = True    # renormalise the top-k gates to sum 1
+    moe_capacity_factor: float = 1.25  # 0 => dropless (every routed
+                                  # token reaches its expert)
+    moe_experts_held: int = 0     # experts whose weights a layer holds
+                                  # (0 => all); it routes over all
+                                  # num_experts and computes only these
+    moe_expert_offset: int = 0    # global id of the first held expert
 
     # --- SSM (Mamba-1) ---
     ssm_state: int = 0
@@ -133,6 +148,11 @@ class ArchConfig:
         return (i % self.moe_every) == self.moe_offset
 
     @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights one MoE layer holds."""
+        return self.moe_experts_held or self.num_experts
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -162,7 +182,7 @@ class ArchConfig:
         mult = 3 if self.act == "swiglu" else 2
         if self.is_moe_layer(i):
             per = mult * d * self.moe_d_ff
-            return ((self.num_experts + self.num_shared_experts) * per
+            return ((self.held_experts + self.num_shared_experts) * per
                     + d * self.num_experts)  # router
         return mult * d * self.d_ff
 
@@ -235,6 +255,8 @@ class ArchConfig:
             d_ff=min(self.d_ff, 4 * d) if self.d_ff else 0,
             vocab_size=min(self.vocab_size, 1024),
             num_experts=min(self.num_experts, 4),
+            moe_experts_held=min(self.moe_experts_held, 4),
+            moe_expert_offset=0,
             moe_top_k=min(self.moe_top_k, 2),
             moe_d_ff=min(self.moe_d_ff, 2 * d) if self.moe_d_ff else 0,
             num_shared_experts=min(self.num_shared_experts, 1),

@@ -1,8 +1,13 @@
-"""DeepSeek-V2-Lite 16B [arXiv:2405.04434].
+"""DeepSeek-V2-Lite 16B [arXiv:2405.04434; published config.json at
+huggingface.co/deepseek-ai/DeepSeek-V2-Lite].
 
-Assignment line lists both "MoE 64e top-6" and "2 shared+160 routed"; the
-160-routed figure belongs to full V2 — V2-Lite is 64 routed + 2 shared,
-top-6 (paper Tab. 1). We use 64 routed + 2 shared, top-6, MLA kv_lora=512.
+64 routed + 2 shared SwiGLU experts of 1408, top-6 (paper Tab. 1; the
+160-routed figure belongs to full V2), MLA with kv_lora 512 and no q
+compression, a leading dense layer of 10944. Routing as published:
+softmax gates, greedy top-6, ``norm_topk_prob`` false (gates are not
+renormalised), ``routed_scaling_factor`` 1, and no token dropped in the
+forward pass. Rotary: YaRN, factor 40 over 4096 original positions,
+beta_fast 32, beta_slow 1, mscale = mscale_all_dim = 0.707.
 """
 from repro.configs.base import ArchConfig
 
@@ -27,8 +32,17 @@ CONFIG = ArchConfig(
     num_shared_experts=2,
     moe_top_k=6,
     moe_d_ff=1408,
+    moe_norm_topk=False,
+    moe_capacity_factor=0.0,   # dropless
     first_dense_layers=1,
     rope_theta=10_000.0,
+    rope_scaling_factor=40.0,
+    rope_original_max_pos=4096,
+    rope_beta_fast=32.0,
+    rope_beta_slow=1.0,
+    rope_mscale=0.707,
+    rope_mscale_all_dim=0.707,
+    norm_eps=1e-6,
     act="swiglu",
 )
 

@@ -640,6 +640,21 @@ class PlanCosts:
                                 # is priced per unit instead of by ``P``
     param_x_host: float = 0.0   # serve param tier split (byte fraction
                                 # host-resident, TieredVector rounding)
+    # ---- stacks whose layers differ (e.g. a dense layer, then MoE) ----
+    layer_P: Tuple[int, ...] = ()   # per-layer flat elements; when
+                                # non-empty, layer l is priced by
+                                # layer_P[l] instead of ``P``
+    layer_act_res_bytes: Tuple[int, ...] = ()   # the same for
+                                # ``act_res_bytes``
+
+    def P_of(self, l: int) -> int:
+        """Layer ``l``'s flat parameter elements."""
+        return self.layer_P[l] if self.layer_P else self.P
+
+    def act_of(self, l: int) -> int:
+        """Layer ``l``'s vjp-residual payload bytes."""
+        return (self.layer_act_res_bytes[l] if self.layer_act_res_bytes
+                else self.act_res_bytes)
 
     @staticmethod
     def from_engine(eng) -> "PlanCosts":
@@ -648,12 +663,17 @@ class PlanCosts:
         item = eng.dtype.itemsize
         head_nbytes = 4 * (eng.embed.size + eng.unembed.size
                            + eng.final_norm.size)
+        # the single-rank engine sizes every layer; the DP engine's
+        # layers are all P
+        sizes = tuple(getattr(eng, "layer_P", ()))
+        acts = tuple(getattr(eng, "layer_act_nbytes", ()))
         return PlanCosts(
-            P=eng.P, param_itemsize=item,
+            P=sizes[0] if sizes else eng.P, param_itemsize=item,
             ckpt_elems=ocfg.micro_batch * ocfg.seq_len * eng.cfg.d_model,
             act_itemsize=item, ratios=ocfg.ratios, alpha=ocfg.alpha,
             ranks=getattr(eng, "R", 1), head_nbytes=head_nbytes,
-            act_res_bytes=getattr(eng, "act_nbytes", 0))
+            act_res_bytes=acts[0] if acts else getattr(eng, "act_nbytes", 0),
+            layer_P=sizes, layer_act_res_bytes=acts)
 
 
 def _khost(x: float, n: int) -> int:
@@ -665,6 +685,12 @@ def _seg_ssd(n: int, x_host: float, lo: int, hi: int) -> int:
     """SSD-touching elements of a [lo, hi) segment read/write of an
     n-element tiered vector (mirrors TieredVector.read_range/write_seg)."""
     return max(0, hi - max(lo, _khost(x_host, n)))
+
+
+#: ops whose bytes scale with their layer's parameter count
+_PARAM_SIZED = frozenset((Op.FETCH_PARAM, Op.ALLGATHER, Op.GRAD_SPILL,
+                          Op.GRAD_FETCH_ACC, Op.WRITEBACK_GRAD, Op.OPT_LATE,
+                          Op.REDUCE_SCATTER))
 
 
 def plan_traffic(plan: Plan, costs: PlanCosts):
@@ -690,10 +716,12 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
     a = costs.act_itemsize
     u = E * a                                   # one boundary tensor
     ps = costs.param_itemsize
-    P = costs.P
+    if costs.layer_P and R > 1:
+        raise ValueError("per-layer sizes are for single-rank plans; the "
+                         "DP engine shards one size P")
     kc = _khost(x.ckpt, E)
     Mr = plan.spec.M // R
-    bounds = shard_bounds(P, R)
+    bounds = shard_bounds(costs.P, R)
     out = [defaultdict(int) for _ in range(R)]
 
     def owner(m: int) -> int:
@@ -717,6 +745,10 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
 
     for op in plan.ops:
         k = op.op
+        if k in _PARAM_SIZED:
+            # per-layer sizes are single-rank: layer l is one shard [0, P_l)
+            P = costs.P_of(op.l)
+            bounds_l = [(0, P)] if costs.layer_P else bounds
         if k is Op.FETCH_PARAM:
             if costs.param_unit_nbytes:
                 # serving: per-unit param blob, tiered by byte fraction
@@ -728,7 +760,7 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
             add(0, "param", "ssd->cpu", (P - _khost(x.param, P)) * ps)
             add(0, "param", "cpu->gpu", P * ps)
         elif k is Op.ALLGATHER:
-            for r, (lo, hi) in enumerate(bounds):
+            for r, (lo, hi) in enumerate(bounds_l):
                 n_r = hi - lo
                 add(r, "param", "ssd->cpu",
                     (n_r - _khost(x.param, n_r)) * ps)
@@ -764,14 +796,14 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
             add(r, "ckpt", "cpu->gpu", u)
         elif k is Op.SPILL_ACT:
             r = owner(op.m)
-            A = costs.act_res_bytes
+            A = costs.act_of(op.l)
             add(r, "act", "gpu->cpu", A)
             ka = _khost(x.act, A)            # coordinator rounding (bytes)
             if ka < A:
                 add(r, "act", "cpu->ssd", A - ka)
         elif k is Op.FETCH_ACT:
             r = owner(op.m)
-            A = costs.act_res_bytes
+            A = costs.act_of(op.l)
             ka = _khost(x.act, A)
             if ka < A:
                 # unlike ckpt tails, the CPU copy is dropped as soon as
@@ -811,13 +843,13 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
             # plan end (the byte count is what an engine run followed
             # by finish() measures; PREFETCH_OPT hints only move the
             # state reads earlier, never change them)
-            for r, (lo, hi) in enumerate(bounds):
+            for r, (lo, hi) in enumerate(bounds_l):
                 n_r = hi - lo
                 opt_segment(r, n_r, int(round((1.0 - costs.alpha) * n_r)),
                             n_r)
         elif k is Op.REDUCE_SCATTER:
             ring = (R - 1) * (P * 4) // R
-            for r, (lo, hi) in enumerate(bounds):
+            for r, (lo, hi) in enumerate(bounds_l):
                 n_r = hi - lo
                 add(r, "grad", "gpu->net", ring)
                 add(r, "grad", "net->gpu", ring)

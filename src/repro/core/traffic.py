@@ -11,6 +11,7 @@ offload engine's measured byte counters are validated against them.
 from __future__ import annotations
 
 import dataclasses
+import numbers
 from typing import Optional
 
 
@@ -165,7 +166,7 @@ class ActTraffic:
         return self.spill + self.fetch + self.ssd_spill + self.ssd_reread
 
 
-def act_spill_traffic(A: float, M: int, L: int,
+def act_spill_traffic(A, M: int, L: int,
                       x_act: float = 0.0) -> ActTraffic:
     """Closed-form per-iteration activation-stream counters: ``L·M``
     spills and fetches of ``A`` bytes each (one per (layer,
@@ -174,13 +175,19 @@ def act_spill_traffic(A: float, M: int, L: int,
     :func:`repro.core.plan.plan_traffic` apply). Wave size does not
     enter: activations are written and read within one wave, with no
     §4.2 keep discipline (the stream is strictly FIFO per micro-batch).
+    ``A`` is one size for every layer or a sequence of L per-layer
+    sizes (a stack whose layers differ).
     """
-    tail = A - int(round(x_act * A))
+    sizes = [A] * L if isinstance(A, numbers.Real) else list(A)
+    if len(sizes) != L:
+        raise ValueError(f"{len(sizes)} per-layer sizes for L={L}")
+    total = sum(sizes)
+    tail = sum(a - int(round(x_act * a)) for a in sizes)
     return ActTraffic(
-        spill=L * M * A,
-        fetch=L * M * A,
-        ssd_spill=L * M * tail,
-        ssd_reread=L * M * tail,
+        spill=M * total,
+        fetch=M * total,
+        ssd_spill=M * tail,
+        ssd_reread=M * tail,
     )
 
 

@@ -17,7 +17,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import apply_rope, dense_init, init_rms_scale, rms_norm
+from repro.models.common import (apply_rope, dense_init, init_rms_scale,
+                                 rms_norm, rope_setup)
 
 _NEG_INF = -1e30
 
@@ -381,7 +382,10 @@ def mla_apply(params, x, *, cfg, theta: float, cache: Optional[MLACache] = None,
     H = cfg.num_heads
     nope, rope, vhd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
-    scale = 1.0 / math.sqrt(nope + rope)
+    # YaRN (when configured) sets the rope frequencies, scales cos/sin,
+    # and multiplies the softmax scale by mscale_all_dim's m^2
+    inv_freq, rot_scale, temp = rope_setup(cfg, rope, theta)
+    scale = temp / math.sqrt(nope + rope)
 
     q = (x @ params["wq"]).reshape(B, S, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
@@ -393,8 +397,10 @@ def mla_apply(params, x, *, cfg, theta: float, cache: Optional[MLACache] = None,
         positions = jnp.full((1,), pos, jnp.int32)
     else:
         positions = jnp.arange(S, dtype=jnp.int32)
-    q_rope = apply_rope(q_rope, positions[None, :], theta)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions[None, :], theta)[:, :, 0, :]
+    q_rope = apply_rope(q_rope, positions[None, :], theta, inv_freq,
+                        rot_scale)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions[None, :], theta,
+                        inv_freq, rot_scale)[:, :, 0, :]
 
     new_cache = None
     if mode == "decode":

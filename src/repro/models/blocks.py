@@ -148,8 +148,11 @@ def _build_cross_kv(params, enc_out, cfg) -> attn_lib.KVCache:
 
 
 def block_apply(params, x, cfg, kind: LayerKind, *, mode: str = "train",
-                cache=None, pos=None, enc_out=None, scan_impl: str = "jnp"):
-    """Pre-norm residual block. Returns (x, new_cache, aux_loss)."""
+                cache=None, pos=None, enc_out=None, scan_impl: str = "jnp",
+                moe_counts: bool = False):
+    """Pre-norm residual block. Returns (x, new_cache, aux_loss); with
+    ``moe_counts`` an MoE block returns (x, new_cache, (aux_loss, tokens
+    routed to each held expert))."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, Any] = {}
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
@@ -188,7 +191,12 @@ def block_apply(params, x, cfg, kind: LayerKind, *, mode: str = "train",
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
         if kind.moe:
             from repro.models import moe_ep
-            y2, aux = moe_ep.moe_dispatch(params["moe"], h2, cfg)
+            if moe_counts:      # one device: the held experts' share
+                y2, a, counts = moe_lib.moe_apply(params["moe"], h2, cfg,
+                                                  with_counts=True)
+                aux = (a, counts)
+            else:
+                y2, aux = moe_ep.moe_dispatch(params["moe"], h2, cfg)
         else:
             y2 = mlp_apply(params["mlp"], h2, cfg.act)
         x = x + y2

@@ -95,7 +95,9 @@ def _moe_ep_local(params, x, cfg, *, axis: str, all_axes,
     logits = xf.astype(jnp.float32) @ params["router"]          # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = lax.top_k(probs, K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_norm_topk:
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True),
+                                            1e-9)
 
     # global aux load-balance loss (Switch-style), averaged over the mesh
     me = jnp.mean(probs, axis=0)
@@ -106,7 +108,7 @@ def _moe_ep_local(params, x, cfg, *, axis: str, all_axes,
 
     # ---- stage 1: bucket assignments by OWNER shard ----
     flat_e = gate_idx.reshape(T * K)                 # global expert ids
-    flat_t = jnp.repeat(jnp.arange(T), K)
+    flat_t = jnp.arange(T * K) // K
     flat_g = gate_vals.reshape(T * K)
     owner = flat_e // E_loc
     C1 = max(1, int(math.ceil(T * K / m * capacity_factor)))

@@ -57,6 +57,7 @@ CAT_HINT = "hint"              # hint lifecycle (issued -> outcome)
 CAT_COPY = "copy"              # host<->device copy on the calling thread
 CAT_WAIT = "wait"              # a thread blocked on other work
 CAT_WORK = "work"              # reads, writes and Adam in a request body
+CAT_COUNTER = "counter"        # an instant carrying a per-step count
 
 #: the plan executor's track: its op spans and the leaf spans of the
 #: copies and waits that run inside them on the executor's thread

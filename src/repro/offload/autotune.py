@@ -237,8 +237,10 @@ class AutotuneController:
         eng = self.eng
         W, depth, pol, path_pol = knobs
         R = getattr(eng, "R", 1)
-        w = engine_workload(eng.ocfg, eng.cfg, eng.P,
-                            eng.dtype.itemsize, eng.act_nbytes)
+        w = engine_workload(eng.ocfg, eng.cfg,
+                            getattr(eng, "layer_P", eng.P),
+                            eng.dtype.itemsize,
+                            getattr(eng, "layer_act_nbytes", eng.act_nbytes))
         sol = solve_config(machine, w, eng.ocfg.num_microbatches,
                            eng.ocfg.alpha, num_gpus=R,
                            wave=None if R > 1 else W,

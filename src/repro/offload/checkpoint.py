@@ -72,6 +72,13 @@ def _assemble(eng, attr: str, l: int, dtype) -> np.ndarray:
     return np.asarray(getattr(eng, attr)[l].read(), dtype).copy()
 
 
+def _p_meta(eng):
+    """The layer size the manifest records: ``P`` for a stack whose
+    layers are alike, else the list of per-layer sizes."""
+    return int(eng.P) if eng.P is not None else [int(n) for n in
+                                                 eng.layer_P]
+
+
 _VEC_ATTRS = (("p", "p_vecs"), ("master", "m_master"),
               ("m", "m_m"), ("v", "m_v"))
 _HEAD_TENSORS = ("embed", "unembed", "final_norm")
@@ -138,7 +145,7 @@ def save_checkpoint(eng, directory: str) -> str:
                          "shape": list(arr.shape),
                          "crc32c": crc32c(data)}
     doc = {"version": CKPT_VERSION,
-           "meta": {"L": int(eng.L), "P": int(eng.P), "step_num": gen,
+           "meta": {"L": int(eng.L), "P": _p_meta(eng), "step_num": gen,
                     "param_dtype": str(np.dtype(eng.ocfg.param_dtype)),
                     "arch": getattr(eng.cfg, "name", ""),
                     "ranks": int(getattr(eng, "R", 1))},
@@ -195,7 +202,7 @@ def restore_checkpoint(eng, directory: str) -> int:
     doc = load_manifest(directory)
     meta = doc["meta"]
     pdt = str(np.dtype(eng.ocfg.param_dtype))
-    for key, have in (("L", int(eng.L)), ("P", int(eng.P)),
+    for key, have in (("L", int(eng.L)), ("P", _p_meta(eng)),
                       ("param_dtype", pdt)):
         if meta.get(key) != have:
             raise CheckpointError(

@@ -128,11 +128,15 @@ class DataParallelOffloadEngine:
 
     def __init__(self, cfg, ocfg: OffloadConfig, key, workdir: str,
                  ranks: int = 2):
-        assert cfg.family in ("dense",), "engine drives homogeneous GPT stacks"
+        plan = blk.build_plan(cfg)
+        if cfg.family != "dense" or len(set(plan.all_kinds())) != 1:
+            raise ValueError(
+                f"the data-parallel offload engine drives stacks of one "
+                f"dense layer kind; {cfg.name!r} (family {cfg.family!r}) "
+                "has other kinds — run it on the single-rank "
+                "OffloadEngine (make_engine with num_ranks=1)")
         assert ocfg.schedule == "vertical", \
             "data-parallel offload implements the vertical schedule"
-        plan = blk.build_plan(cfg)
-        assert len(plan.period) == 1 and not plan.prefix and not plan.suffix
         M = ocfg.num_microbatches
         if M % ranks:
             raise ValueError(
@@ -140,7 +144,7 @@ class DataParallelOffloadEngine:
                 f"{ranks} ranks (uneven sharding is a ROADMAP follow-on)")
         self.cfg = cfg
         self.ocfg = ocfg
-        self.kind = plan.period[0]
+        self.kind = plan.all_kinds()[0]
         self.L = cfg.num_layers
         self.R = ranks
         self.Mr = M // ranks

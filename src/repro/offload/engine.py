@@ -77,11 +77,13 @@ from repro.offload.coordinators import (ActivationCoordinator,
                                         InterLayerTensorCoordinator,
                                         OptimizerStepCoordinator,
                                         ParameterCoordinator)
-from repro.offload.executor import execute_plan
+from repro.obs.tracer import CAT_COUNTER
+from repro.offload.executor import LayerFns, execute_plan
 from repro.offload.stores import HostStore, SSDStore, TieredVector, TrafficMeter
 from repro.optim.cpu_adam import CpuAdam
 
 __all__ = ["OffloadConfig", "OffloadEngine", "build_block_fns",
+           "build_layer_fns", "kind_label",
            "bind_block_fns", "mb_order", "split_microbatches",
            "shifted_labels", "act_residual_nbytes",
            "resolve_activation_policy", "engine_workload"]
@@ -225,15 +227,33 @@ def _adam_device(p, m, v, g, step, lr):
     return (p.astype(jnp.float32) - lr * up).astype(p.dtype), m2, v2
 
 
-def build_block_fns(cfg, kind, unflatten) -> Dict[str, object]:
-    """Jitted per-layer / embedding / head functions, shared by the
-    single-rank and data-parallel engines. Both engines driving the SAME
-    compiled computations is what makes an R-rank run bit-identical
-    (f32) to a single-rank run — any per-engine recompilation could
-    legally re-fuse and break that."""
+def _named(fn, name: str):
+    """``fn`` renamed, so its jit (and the device trace's module) reads
+    ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def kind_label(kind) -> str:
+    """A layer kind's short name: ``moe`` for an expert layer, else
+    ``dense``."""
+    return "moe" if kind.moe else "dense"
+
+
+def build_layer_fns(cfg, kind, unflatten, suffix: str = ""
+                    ) -> Dict[str, object]:
+    """One layer kind's jitted forward, residual forward and backward,
+    named ``layer_fwd<suffix>`` etc. An MoE kind's forwards also return
+    the tokens routed to each held expert: ``layer_fwd`` gives (y,
+    counts), ``layer_fwd_res`` (y, vjp, counts); its auxiliary
+    load-balance loss stays out of the training loss."""
 
     def layer_fwd(p_flat, x):
         lp = unflatten(p_flat)
+        if kind.moe:
+            y, _, (_, counts) = blk.block_apply(lp, x, cfg, kind,
+                                                mode="train", moe_counts=True)
+            return y, counts
         y, _, _ = blk.block_apply(lp, x, cfg, kind, mode="train")
         return y
 
@@ -243,12 +263,27 @@ def build_block_fns(cfg, kind, unflatten) -> Dict[str, object]:
         these residuals — spill restores them from storage, recompute
         re-runs this function at backward time — so the two policies'
         gradients are bitwise-identical by construction."""
-        return jax.vjp(lambda p, xx: layer_fwd(p, xx), p_flat, x)
+        return jax.vjp(lambda p, xx: layer_fwd(p, xx), p_flat, x,
+                       has_aux=kind.moe)
 
     def layer_bwd_res(vjp, dy):
         """Backward from saved/recomputed residuals (no forward pass)."""
         dp, dx = vjp(dy)
         return dx, dp.astype(jnp.float32)
+
+    return {name: jax.jit(_named(fn, name + suffix))
+            for name, fn in (("layer_fwd", layer_fwd),
+                             ("layer_fwd_res", layer_fwd_res),
+                             ("layer_bwd_res", layer_bwd_res))}
+
+
+def build_block_fns(cfg, kind, unflatten, suffix: str = ""
+                    ) -> Dict[str, object]:
+    """Jitted per-layer / embedding / head functions, shared by the
+    single-rank and data-parallel engines. Both engines driving the SAME
+    compiled computations is what makes an R-rank run bit-identical
+    (f32) to a single-rank run — any per-engine recompilation could
+    legally re-fuse and break that."""
 
     def embed_fwd(embed, tokens):
         return embed[tokens]
@@ -271,9 +306,7 @@ def build_block_fns(cfg, kind, unflatten) -> Dict[str, object]:
         return vjp(dx)[0]
 
     return {
-        "layer_fwd": jax.jit(layer_fwd),
-        "layer_fwd_res": jax.jit(layer_fwd_res),
-        "layer_bwd_res": jax.jit(layer_bwd_res),
+        **build_layer_fns(cfg, kind, unflatten, suffix),
         "embed": jax.jit(embed_fwd),
         "head_bwd": jax.jit(head_bwd),
         "embed_bwd": jax.jit(embed_bwd),
@@ -299,16 +332,16 @@ def act_residual_nbytes(j_layer_fwd_res, P: int, dtype, micro_batch: int,
     what each ``SPILL_ACT``/``FETCH_ACT`` moves — via ``jax.eval_shape``
     (no compute, no allocation). Shared by both engines and by
     ``PlanCosts.from_engine`` through the ``act_nbytes`` attribute."""
-    _, res = jax.eval_shape(
+    res = jax.eval_shape(
         j_layer_fwd_res,
         jax.ShapeDtypeStruct((P,), dtype),
-        jax.ShapeDtypeStruct((micro_batch, seq_len, d_model), dtype))
+        jax.ShapeDtypeStruct((micro_batch, seq_len, d_model), dtype))[1]
     return sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
                for leaf in jax.tree.leaves(res))
 
 
-def resolve_activation_policy(ocfg: OffloadConfig, cfg, P: int,
-                              itemsize: int, act_nbytes: int) -> str:
+def resolve_activation_policy(ocfg: OffloadConfig, cfg, P, itemsize: int,
+                              act_nbytes) -> str:
     """Resolve the ``activation_policy`` knob to "recompute"|"spill".
     "auto" prices both policies with the perf model
     (:func:`repro.core.perfmodel.pick_activation_policy`) using
@@ -335,26 +368,34 @@ def resolve_activation_policy(ocfg: OffloadConfig, cfg, P: int,
                                   > 0)
 
 
-def engine_workload(ocfg: OffloadConfig, cfg, P: int, itemsize: int,
-                    act_nbytes: int):
+def _per_layer(x, L: int) -> List[int]:
+    """A per-layer size given as one int (every layer alike) or as a
+    sequence of L."""
+    return [int(x)] * L if np.ndim(x) == 0 else [int(v) for v in x]
+
+
+def engine_workload(ocfg: OffloadConfig, cfg, P, itemsize: int,
+                    act_nbytes):
     """The ENGINE-accurate :class:`repro.core.perfmodel.Workload`: the
     FLOP model comes from the one place it is maintained
     (``Workload.from_config``); only the byte fields are overridden
     with this engine's actual sizes — its dtype, its flat layer
-    vector, its measured residual payload. The one workload both the
-    "auto" activation-policy pricing and the online autotuner solve
-    against (an autotuner solving the paper's bf16 defaults would
+    vectors, its measured residual payloads. ``P`` and ``act_nbytes``
+    are one size for every layer or one per layer. The one workload
+    both the "auto" activation-policy pricing and the online autotuner
+    solve against (an autotuner solving the paper's bf16 defaults would
     retune the wrong machine)."""
     from repro.core.perfmodel import Workload
     L = cfg.num_layers
     tokens = ocfg.micro_batch * ocfg.seq_len
+    n = sum(_per_layer(P, L))
     return dataclasses.replace(
         Workload.from_config(cfg, ocfg.micro_batch, ocfg.seq_len),
-        ms=L * P * itemsize,
+        ms=n * itemsize,
         cs=L * tokens * cfg.d_model * itemsize,
-        os_bytes=3 * L * P * 4,
-        grad_bytes=L * P * 4,
-        as_bytes=L * act_nbytes,
+        os_bytes=3 * n * 4,
+        grad_bytes=n * 4,
+        as_bytes=sum(_per_layer(act_nbytes, L)),
     )
 
 
@@ -408,13 +449,21 @@ def shifted_labels(tok_mb: np.ndarray):
 
 
 class OffloadEngine:
+    #: families whose layers are plain decoder blocks on one token stream
+    FAMILIES = ("dense", "moe")
+
     def __init__(self, cfg, ocfg: OffloadConfig, key, workdir: str):
-        assert cfg.family in ("dense",), "engine drives homogeneous GPT stacks"
-        plan = blk.build_plan(cfg)
-        assert len(plan.period) == 1 and not plan.prefix and not plan.suffix
+        if cfg.family not in self.FAMILIES:
+            raise ValueError(
+                f"the offload engine drives {self.FAMILIES} stacks, not "
+                f"family {cfg.family!r}")
         self.cfg = cfg
         self.ocfg = ocfg
-        self.kind = plan.period[0]
+        # the layer plan's prefix + periods + suffix, one kind per layer
+        # (e.g. DeepSeek's leading dense layer, then MoE layers)
+        self.layer_kinds = tuple(blk.build_plan(cfg).all_kinds())
+        self.kinds = tuple(dict.fromkeys(self.layer_kinds))
+        self.kind = self.kinds[0]
         self.L = cfg.num_layers
         self.dtype = jnp.dtype(ocfg.param_dtype)
         self.meter = TrafficMeter()
@@ -447,26 +496,33 @@ class OffloadEngine:
         self.m_master: List[TieredVector] = []
         self.m_m: List[TieredVector] = []
         self.m_v: List[TieredVector] = []
-        tmpl = None
-        for l in range(self.L):
-            lp = blk.block_init(keys[l], cfg, self.kind, dtype=self.dtype)
+        tmpl = {}                   # kind -> (treedef, leaf shapes)
+        layer_P = []
+        for l, kind in enumerate(self.layer_kinds):
+            lp = blk.block_init(keys[l], cfg, kind, dtype=self.dtype)
             flat, treedef, shapes = _flatten_tree(lp)
             flat = flat.astype(ocfg.param_dtype)
-            if tmpl is None:
-                tmpl = (treedef, shapes)
-                self.P = flat.size
-            pv = TieredVector(f"param:{l}", self.P, ocfg.param_dtype,
+            tmpl.setdefault(kind, (treedef, shapes))
+            n = flat.size
+            layer_P.append(n)
+            pv = TieredVector(f"param:{l}", n, ocfg.param_dtype,
                               x.param, self.host, self.ssd, "param")
             pv.write_full(flat)
             self.p_vecs.append(pv)
             for name, lst, init in (("master", self.m_master, flat.astype(np.float32)),
-                                    ("m", self.m_m, np.zeros(self.P, np.float32)),
-                                    ("v", self.m_v, np.zeros(self.P, np.float32))):
-                tv = TieredVector(f"{name}:{l}", self.P, np.float32,
+                                    ("m", self.m_m, np.zeros(n, np.float32)),
+                                    ("v", self.m_v, np.zeros(n, np.float32))):
+                tv = TieredVector(f"{name}:{l}", n, np.float32,
                                   x.opt, self.host, self.ssd, "opt")
                 tv.write_full(init)
                 lst.append(tv)
-        self._unflatten = _make_unflatten(tmpl[0], tmpl[1], self.dtype)
+        #: each layer's flat vector size; ``P`` is the common one of a
+        #: stack whose layers are alike, None otherwise
+        self.layer_P = tuple(layer_P)
+        self.P = layer_P[0] if len(set(layer_P)) == 1 else None
+        self._unflattens = {k: _make_unflatten(*tmpl[k], self.dtype)
+                            for k in self.kinds}
+        self._unflatten = self._unflattens[self.kind]
 
         # embedding / head resident on device (+ their own device Adam)
         from repro.models.common import embed_init, init_rms_scale
@@ -495,12 +551,17 @@ class OffloadEngine:
 
         self._build_jit_fns()
         # size the activation stream exactly (one (layer, mb) residual
-        # payload) and resolve the recompute/spill/auto policy knob
-        self.act_nbytes = act_residual_nbytes(
-            self.j_layer_fwd_res, self.P, self.dtype, ocfg.micro_batch,
-            ocfg.seq_len, cfg.d_model)
+        # payload per kind) and resolve the recompute/spill/auto knob
+        act = {k: act_residual_nbytes(
+            self._kind_jits[k]["layer_fwd_res"], self.layer_P[
+                self.layer_kinds.index(k)], self.dtype, ocfg.micro_batch,
+            ocfg.seq_len, cfg.d_model) for k in self.kinds}
+        self.layer_act_nbytes = tuple(act[k] for k in self.layer_kinds)
+        self.act_nbytes = act[self.kind] if len(set(act.values())) == 1 \
+            else None
         self.act_policy = resolve_activation_policy(
-            ocfg, cfg, self.P, self.dtype.itemsize, self.act_nbytes)
+            ocfg, cfg, self.layer_P, self.dtype.itemsize,
+            self.layer_act_nbytes)
         self.act_fallbacks = 0      # micro-batches degraded to recompute
         # cross-stream lookahead state: per-op stall meters, adaptive
         # skip counters, and the backpressure knob the executor reads
@@ -514,8 +575,49 @@ class OffloadEngine:
 
     # ------------------------------------------------------------------
     def _build_jit_fns(self):
-        bind_block_fns(self, build_block_fns(self.cfg, self.kind,
-                                             self._unflatten))
+        """One set of layer jits per kind. A stack of one dense kind
+        keeps the plain names (``layer_fwd``, ...); otherwise each
+        kind's carry its label (``layer_bwd_res_moe``), so a device
+        trace splits the step by kind."""
+        def suffix(k):
+            plain = len(self.kinds) == 1 and not k.moe
+            return "" if plain else "_" + kind_label(k)
+        fns = build_block_fns(self.cfg, self.kind, self._unflatten,
+                              suffix(self.kind))
+        bind_block_fns(self, fns)
+        self._kind_jits = {k: build_layer_fns(self.cfg, k,
+                                              self._unflattens[k], suffix(k))
+                           for k in self.kinds[1:]}
+        self._kind_jits[self.kind] = fns
+        self._moe_counts: List[Tuple[int, jax.Array]] = []
+        self.moe_tokens: Dict[str, int] = {}
+        self.layer_fns = [
+            LayerFns(j["layer_fwd"], j["layer_fwd_res"], j["layer_bwd_res"],
+                     on_counts=(lambda c, l=l: self._moe_counts.append((l, c)))
+                     if k.moe else None)
+            for l, k in enumerate(self.layer_kinds)
+            for j in (self._kind_jits[k],)]
+        self.layer_labels = tuple(kind_label(k) for k in self.layer_kinds)
+
+    def _note_moe_counts(self) -> None:
+        """Fold the step's per-(layer, micro-batch) held-expert token
+        counts into ``moe_tokens``: ``moe.tokens_held``, the routed
+        assignments the held experts took, and ``moe.tokens_max_expert``,
+        the most one held expert of one layer took in the step. Read
+        after the step's loss, whose readback already waited on them."""
+        per_layer: Dict[int, np.ndarray] = {}
+        for l, c in zip((l for l, _ in self._moe_counts),
+                        jax.device_get([c for _, c in self._moe_counts])):
+            per_layer[l] = per_layer.get(l, 0) + np.asarray(c, np.int64)
+        self._moe_counts.clear()
+        self.moe_tokens = {
+            "moe.tokens_held": int(sum(v.sum() for v in per_layer.values())),
+            "moe.tokens_max_expert": int(max(v.max()
+                                             for v in per_layer.values()))}
+        if self.tracer.enabled:
+            for name, v in self.moe_tokens.items():
+                self.tracer.instant("moe", name, CAT_COUNTER, value=v,
+                                    step=self.step_num)
 
     # ------------------------------------------------------------------
     def _mb_order(self, l: int) -> List[int]:
@@ -541,7 +643,11 @@ class OffloadEngine:
         return insert_prefetch(plan, depth=depth)
 
     def train_step(self, tokens: np.ndarray) -> float:
-        return execute_plan(self, self._plan, tokens)
+        self._moe_counts.clear()
+        loss = execute_plan(self, self._plan, tokens)
+        if self._moe_counts:
+            self._note_moe_counts()
+        return loss
 
     # ------------------------------------------------------------------
     def _split_tokens(self, tokens):
@@ -631,8 +737,8 @@ class OffloadEngine:
             setattr(self.ocfg, k, v)
         if activation_policy is not None:
             self.act_policy = resolve_activation_policy(
-                self.ocfg, self.cfg, self.P, self.dtype.itemsize,
-                self.act_nbytes)
+                self.ocfg, self.cfg, self.layer_P, self.dtype.itemsize,
+                self.layer_act_nbytes)
             self.act_adaptive = (self.ocfg.activation_policy == "auto"
                                  and self.act_policy == "spill")
         self._plan = self._compile_plan()

@@ -96,6 +96,70 @@ from repro.obs.tracer import CAT_PLAN, EXEC_TRACK, NO_SPAN
 from repro.offload.coordinators import _to_device, _to_host, _xfer
 
 
+class LayerFns:
+    """One layer kind's compiled forward and backward as the executor
+    calls them. An MoE kind's forwards also yield the tokens routed to
+    each held expert, handed to ``on_counts``; the backward-time
+    recompute drops them (its forward already counted)."""
+    __slots__ = ("fwd", "fwd_res", "bwd_res", "on_counts")
+
+    def __init__(self, fwd, fwd_res, bwd_res, on_counts=None):
+        self.fwd, self.fwd_res, self.bwd_res = fwd, fwd_res, bwd_res
+        self.on_counts = on_counts
+
+    def forward(self, p, x):
+        if self.on_counts is None:
+            return self.fwd(p, x)
+        y, counts = self.fwd(p, x)
+        self.on_counts(counts)
+        return y
+
+    def forward_res(self, p, x):
+        """(y, vjp residuals) for the activation stream."""
+        if self.on_counts is None:
+            return self.fwd_res(p, x)
+        y, res, counts = self.fwd_res(p, x)
+        self.on_counts(counts)
+        return y, res
+
+    def residuals(self, p, x):
+        return self.fwd_res(p, x)[1]
+
+
+def _layer_fns(eng, L: int):
+    """Each layer's :class:`LayerFns`: the engine's per-kind list, or
+    one set of its ``j_layer_*`` jits for every layer (the DP engine)."""
+    fns = getattr(eng, "layer_fns", None)
+    if fns is not None:
+        return fns
+    return [LayerFns(eng.j_layer_fwd, eng.j_layer_fwd_res,
+                     eng.j_layer_bwd_res)] * L
+
+
+def _op_args(eng, op) -> dict:
+    """Span args naming what a parameter, gradient or optimizer op of
+    layer ``op.l`` moves: its kind and its bytes (the low-precision
+    parameter vector, or the f32 gradient)."""
+    labels = getattr(eng, "layer_labels", None)
+    sizes = getattr(eng, "layer_P", None)
+    if labels is None or sizes is None or op.l < 0:
+        return {}
+    n = sizes[op.l]
+    item = eng.dtype.itemsize if op.op is Op.FETCH_PARAM else 4
+    return {"kind": labels[op.l], "bytes": int(n * item)}
+
+
+#: the ops whose spans carry :func:`_op_args`
+_SIZED_OPS = frozenset((Op.FETCH_PARAM, Op.WRITEBACK_GRAD, Op.OPT_LATE,
+                        Op.PREFETCH_OPT))
+
+
+def _layer_size(eng, l: int) -> int:
+    """Layer ``l``'s flat vector size (the DP engine's are all ``P``)."""
+    sizes = getattr(eng, "layer_P", None)
+    return eng.P if sizes is None else sizes[l]
+
+
 def _ranks(eng):
     """The engine's rank stacks: the DP engine's ``ranks`` list, or the
     single-rank engine itself (it exposes the same coordinator attrs)."""
@@ -166,6 +230,7 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
     op_seconds = eng.op_seconds
     tracer = getattr(eng, "tracer", None)
     rec = tracer is not None and tracer.enabled
+    lfs = _layer_fns(eng, plan.spec.L)
     wave = -1                       # becomes 0 at the first PHASE("fwd")
     regs = {}                       # transient device tensors
     p_dev = None                    # current layer's params
@@ -198,7 +263,8 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
             span = tracer.span(
                 EXEC_TRACK, k.name, CAT_PLAN, l=op.l, m=op.m, wave=wave,
                 rank=(op.m // Mr if multi and op.m >= 0 else 0),
-                step=step) if rec else NO_SPAN
+                step=step, **(_op_args(eng, op) if k in _SIZED_OPS
+                              else {})) if rec else NO_SPAN
             with span:
                 if k is Op.FETCH_CKPT:
                     regs[("x", op.m)] = \
@@ -207,11 +273,11 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
                     x_in = regs.pop(("x", op.m))
                     if spill:
                         # materialise the vjp residuals for the act stream
-                        y, res = eng.j_layer_fwd_res(p_dev, x_in)
+                        y, res = lfs[op.l].forward_res(p_dev, x_in)
                         regs[("y", op.m)] = y
                         regs[("res", op.m)] = res
                     else:
-                        regs[("y", op.m)] = eng.j_layer_fwd(p_dev, x_in)
+                        regs[("y", op.m)] = lfs[op.l].forward(p_dev, x_in)
                 elif k is Op.SPILL_ACT:
                     res = regs.pop(("res", op.m))
                     rk = rank_of(op.m)
@@ -278,11 +344,11 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
                     # restores them from the act stream, recompute re-runs
                     # the residual-returning forward on the fetched ckpt —
                     # so spill/recompute gradients are bitwise-identical.
+                    lf = lfs[op.l]
                     res = regs.pop(("res", op.m), None)
                     if res is None:
-                        _, res = eng.j_layer_fwd_res(p_dev,
-                                                     regs.pop(("x", op.m)))
-                    dx, dp = eng.j_layer_bwd_res(res, regs.pop(("dy", op.m)))
+                        res = lf.residuals(p_dev, regs.pop(("x", op.m)))
+                    dx, dp = lf.bwd_res(res, regs.pop(("dy", op.m)))
                     if op.acc:
                         gacc = gacc + dp
                     else:
@@ -332,7 +398,7 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
                     else:
                         embed_stash[op.m] = d
                 elif k is Op.GRAD_INIT:
-                    gacc = jnp.zeros((eng.P,), jnp.float32)
+                    gacc = jnp.zeros((_layer_size(eng, op.l),), jnp.float32)
                 elif k is Op.GRAD_SPILL:
                     rk = ranks[0]
                     g = _to_host(eng, gacc, "grad", l=op.l)

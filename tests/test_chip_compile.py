@@ -10,6 +10,7 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and test workers all import every test file.
 """
+import dataclasses
 import os
 
 import jax
@@ -22,7 +23,8 @@ from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.selective_scan import selective_scan_fwd
 from repro.models import blocks as blk
-from repro.offload.engine import _make_unflatten, build_block_fns
+from repro.offload.engine import (_make_unflatten, build_block_fns,
+                                  build_layer_fns)
 
 #: one v5e chip's HBM (Google Cloud documentation, "TPU v5e")
 V5E_HBM_BYTES = 16 << 30
@@ -139,3 +141,29 @@ def test_head_bwd_fits_one_v5e(one_chip, starcoder2_layer):
         tok, _sds((MB, SEQ), jnp.float32, one_chip),
         _sds((), jnp.float32, one_chip)).compile()
     assert _total_bytes(c) < V5E_HBM_BYTES
+
+
+def test_deepseek_v2_moe_layer_fits_one_v5e(one_chip):
+    """DeepSeek-V2-Lite's MoE layer at published widths, holding 16 of 64
+    experts (the benchmark's share): MLA with YaRN, dropless grouped
+    matmuls (``ragged_dot``) and the recompute-policy backward compile
+    for the chip and fit its memory."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                              moe_experts_held=16)
+    kind = blk.build_plan(cfg).all_kinds()[1]
+    assert kind.moe
+    tree = jax.eval_shape(
+        lambda k: blk.block_init(k, cfg, kind, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree.flatten(tree)
+    shapes = [leaf.shape for leaf in leaves]
+    P = sum(int(np.prod(s)) for s in shapes)
+    assert P == 169_611_776
+    fns = build_layer_fns(cfg, kind,
+                          _make_unflatten(treedef, shapes, jnp.bfloat16),
+                          "_moe")
+    p = _sds((P,), jnp.bfloat16, one_chip)
+    x = _sds((MB, SEQ, cfg.d_model), jnp.bfloat16, one_chip)
+    fwd_bwd = jax.jit(lambda p, x, dy: fns["layer_bwd_res"](
+        fns["layer_fwd_res"](p, x)[1], dy)).lower(p, x, x).compile()
+    assert _total_bytes(fwd_bwd) < V5E_HBM_BYTES
