@@ -26,6 +26,10 @@ apart. The run's own output is unchanged; one more line, ``SPLIT
   through the ``bench.sync`` annotation;
 * ``adam_threads``: how many ``opt.adam`` spans ran on each thread
   count, per segment (``"early 8": 8``);
+* ``opt_inplace_share``: the share of master/m/v elements the Adam
+  segments updated in place through host views, from the
+  ``opt.state_read`` spans' ``inplace`` and ``n`` (None where the spans
+  carry no ``inplace``);
 * ``tokens_per_s`` of the traced window, and ``dropped`` spans.
 
 It needs the chip, as ``run.py`` does.
@@ -222,6 +226,18 @@ def adam_threads(spans) -> dict:
     return dict(sorted(out.items()))
 
 
+def opt_inplace_share(spans):
+    """Elements updated through host views ÷ all master/m/v elements of
+    the ``opt.state_read`` spans (three vectors of ``n`` each), or None
+    when no such span carries ``inplace``."""
+    inplace = total = 0
+    for _track, name, _cat, _t0, _t1, a in spans:
+        if name == "opt.state_read" and "inplace" in a:
+            inplace += a["inplace"]
+            total += 3 * a["n"]
+    return inplace / total if total else None
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -251,6 +267,7 @@ def main(argv=None) -> int:
            "per_step": {k: v / steps for k, v in
                         sorted(_seen["totals"].items())},
            "adam_threads": adam_threads(_seen["spans"]),
+           "opt_inplace_share": opt_inplace_share(_seen["spans"]),
            **split(_seen["trace"], _seen["spans"], _seen["sync"], steps)}
     line = json.dumps(out)
     print("SPLIT " + line, file=sys.stderr, flush=True)

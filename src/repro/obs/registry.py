@@ -65,6 +65,9 @@ def build_snapshot(eng) -> Dict[str, object]:
     * time — ``op_seconds``, ``stall_s``, ``phase_time``
     * lookahead — ``lookahead`` (the ``lookahead_stats`` shape),
       ``hint_skips`` / ``act_skips`` / ``act_fallbacks``
+    * host optimizer — ``opt_inplace_elems`` / ``opt_staged_elems``
+      (per-rank master/m/v elements updated through host views and
+      through staged copies, over the engine's life)
     * prediction inputs — ``plan_costs`` (``PlanCosts.from_engine``
       as a dict; ``ratios`` nested)
     * spans — ``trace`` (``Tracer.summary()``: enabled flag, span
@@ -98,6 +101,8 @@ def build_snapshot(eng) -> Dict[str, object]:
         "hint_skips": int(eng.hint_skips),
         "act_skips": int(eng.act_skips),
         "act_fallbacks": int(eng.act_fallbacks),
+        "opt_inplace_elems": [rk.opt_c.inplace_elems for rk in rks],
+        "opt_staged_elems": [rk.opt_c.staged_elems for rk in rks],
         "plan_costs": costs,
         "trace": trace,
     }

@@ -855,13 +855,19 @@ class OptimizerStepCoordinator:
     """Per-layer Adam over tiered f32 state vectors with α-delay.
     Each layer's update runs as an OPTIMIZER_STATE-priority engine
     request: its tiered-vector reads/writes become chunked channel ops
-    that yield to parameter fetches on the same SSD paths."""
+    that yield to parameter fetches on the same SSD paths.
+
+    A segment updates a state vector whose range lies wholly in the
+    host tier through a view of it (``TieredVector.host_view``): Adam
+    writes the host arrays in place, and nothing is copied in or
+    written back. A range that touches the SSD share is staged: read
+    into a copy, updated, written back. ``inplace_elems`` and
+    ``staged_elems`` count the master/m/v elements of each kind."""
 
     def __init__(self, masters: List[TieredVector], ms: List[TieredVector],
                  vs: List[TieredVector], params: List[TieredVector],
                  host: HostStore, meter: TrafficMeter,
-                 engine: IOEngine, adam: CpuAdam, alpha: float,
-                 param_dtype=np.dtype("bfloat16")):
+                 engine: IOEngine, adam: CpuAdam, alpha: float):
         self.masters, self.ms, self.vs = masters, ms, vs
         self.params = params
         self.host = host
@@ -869,7 +875,9 @@ class OptimizerStepCoordinator:
         self.engine = engine
         self.adam = adam
         self.alpha = alpha
-        self.param_dtype = param_dtype
+        self.inplace_elems = 0  # master/m/v elements updated through views
+        self.staged_elems = 0   # ... through staged copies
+        self._count_lock = threading.Lock()
         self._early_futs: Dict[int, IORequest] = {}
         self._late_futs: Dict[int, IORequest] = {}
         self._late_pre: Dict[int, IORequest] = {}   # PREFETCH_OPT reads
@@ -898,9 +906,7 @@ class OptimizerStepCoordinator:
             return
 
         def work():
-            return (self.masters[l].read_range(k, n),
-                    self.ms[l].read_range(k, n),
-                    self.vs[l].read_range(k, n))
+            return self._stage(l, k, n, self._views(l, k, n))
 
         self._late_pre[l] = self.engine.submit(
             work, priority=IOPriority.OPTIMIZER_STATE, category="opt",
@@ -917,19 +923,16 @@ class OptimizerStepCoordinator:
             n = self.masters[l].n
             k = self._k_early(l)
             if k > 0:
-                with self._work_span("opt.state_read", l, "early", k):
-                    mast = self.masters[l].read_range(0, k)
-                    m_ = self.ms[l].read_range(0, k)
-                    v_ = self.vs[l].read_range(0, k)
+                views = self._views(l, 0, k)
+                with self._work_span("opt.state_read", l, "early", k,
+                                     inplace=self._count(views, k)):
+                    state = self._stage(l, 0, k, views)
                 with self._work_span("opt.adam", l, "early", k,
                                      threads=self.adam.threads_for(k)):
-                    self.adam.update(mast, m_, v_, g[:k], step)
+                    self.adam.update(*state, g[:k], step)
                 with self._work_span("opt.state_write", l, "early", k):
-                    self._write_range(self.masters[l], mast, 0, k)
-                    self._write_range(self.ms[l], m_, 0, k)
-                    self._write_range(self.vs[l], v_, 0, k)
-                    lowp = mast.astype(self.param_dtype)
-                    self._write_range(self.params[l], lowp, 0, k)
+                    self._write_back(l, 0, state, views)
+                    self._write_params(l, state[0], 0, k)
             if k < n:
                 self.host.put(f"pending_grad:{l}", g[k:].copy())
 
@@ -947,8 +950,47 @@ class OptimizerStepCoordinator:
         return tr.span(threading.current_thread().name, name, CAT_WORK,
                        l=l, seg=seg, n=n, **args)
 
-    def _write_range(self, vec: TieredVector, data: np.ndarray, lo: int, hi: int):
-        vec.write_seg(data, lo)
+    def _state_vecs(self, l: int) -> tuple:
+        return self.masters[l], self.ms[l], self.vs[l]
+
+    def _views(self, l: int, lo: int, hi: int) -> list:
+        """Host views of layer l's master, m and v over [lo, hi), None
+        for each whose range touches its SSD share."""
+        return [vec.host_view(lo, hi) for vec in self._state_vecs(l)]
+
+    def _count(self, views: list, n: int) -> int:
+        """Count a segment of ``n`` elements into ``inplace_elems`` and
+        ``staged_elems``; return its in-place elements."""
+        inplace = n * sum(v is not None for v in views)
+        with self._count_lock:
+            self.inplace_elems += inplace
+            self.staged_elems += n * len(views) - inplace
+        return inplace
+
+    def _stage(self, l: int, lo: int, hi: int, views: list) -> list:
+        """Master, m and v over [lo, hi): each vector's view, or a
+        staged copy where it has none."""
+        return [view if view is not None else vec.read_range(lo, hi)
+                for vec, view in zip(self._state_vecs(l), views)]
+
+    def _write_back(self, l: int, lo: int, state: list, views: list):
+        """Write the staged vectors of ``state`` back from ``lo``; the
+        views were updated in place."""
+        for vec, arr, view in zip(self._state_vecs(l), state, views):
+            if view is None:
+                vec.write_seg(arr, lo)
+
+    def _write_params(self, l: int, mast: np.ndarray, lo: int, hi: int):
+        """Round master [lo, hi) into layer l's parameters: the host
+        share is cast straight into its view, only the SSD share
+        through a buffer (metered by ``write_seg``)."""
+        vec = self.params[l]
+        h = min(hi, vec.k)
+        if lo < h:
+            np.copyto(vec.host_view(lo, h), mast[:h - lo], casting="unsafe")
+        s = max(lo, h)
+        if s < hi:
+            vec.write_seg(mast[s - lo:].astype(vec.dtype), s)
 
     def flush_late(self, l: int, step: int):
         """Flush the remaining α fraction (gate-ordered before layer
@@ -987,25 +1029,22 @@ class OptimizerStepCoordinator:
             self.la_misses += 1
 
         def work():
-            with self._work_span("opt.state_read", l, "late", n - k):
+            views = self._views(l, k, n)
+            with self._work_span("opt.state_read", l, "late", n - k,
+                                 inplace=self._count(views, n - k)):
                 if pre is not None:
                     # running-or-done by construction (a queued hint was
                     # cancelled above), so this wait is bounded and
                     # cannot deadlock the request workers
-                    mast, m_, v_ = pre.result()
+                    state = pre.result()
                 else:
-                    mast = self.masters[l].read_range(k, n)
-                    m_ = self.ms[l].read_range(k, n)
-                    v_ = self.vs[l].read_range(k, n)
+                    state = self._stage(l, k, n, views)
             with self._work_span("opt.adam", l, "late", n - k,
                                  threads=self.adam.threads_for(n - k)):
-                self.adam.update(mast, m_, v_, g_tail, step)
+                self.adam.update(*state, g_tail, step)
             with self._work_span("opt.state_write", l, "late", n - k):
-                self._write_range(self.masters[l], mast, k, n)
-                self._write_range(self.ms[l], m_, k, n)
-                self._write_range(self.vs[l], v_, k, n)
-                self._write_range(self.params[l],
-                                  mast.astype(self.params[l].dtype), k, n)
+                self._write_back(l, k, state, views)
+                self._write_params(l, state[0], k, n)
 
         self._late_futs[l] = self.engine.submit(
             work, priority=IOPriority.OPTIMIZER_STATE, category="opt",
@@ -1042,7 +1081,8 @@ class OptimizerStepCoordinator:
         flush via the α gate. Unlike :meth:`wait_all` this never
         raises. The completed prefix of the in-place Adam update stays
         applied — a failed step is re-run from a checkpoint, not
-        resumed."""
+        resumed: state updated through host views keeps whatever the
+        segment had written, a staged copy is dropped unwritten."""
         for d in (self._late_pre, self._early_futs, self._late_futs):
             for f in list(d.values()):
                 _cancel_or_drain(f)

@@ -216,8 +216,7 @@ class DataParallelOffloadEngine:
                 x.ckpt, rk.host, rk.ssd, rk.meter, rk.ioe)
             rk.opt_c = OptimizerStepCoordinator(
                 rk.m_master, rk.m_m, rk.m_v, rk.p_vecs, rk.host, rk.meter,
-                rk.ioe, CpuAdam(lr=ocfg.lr), ocfg.alpha,
-                param_dtype=np.dtype(ocfg.param_dtype))
+                rk.ioe, CpuAdam(lr=ocfg.lr), ocfg.alpha)
             # activation shards are per micro-batch OWNER: each rank's
             # residual payloads ride its own IOEngine + SSD path set
             rk.act_c = ActivationCoordinator(x.act, rk.host, rk.ssd,

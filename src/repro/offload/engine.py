@@ -542,8 +542,7 @@ class OffloadEngine:
                                                   self.meter, self.ioe)
         self.opt_c = OptimizerStepCoordinator(
             self.m_master, self.m_m, self.m_v, self.p_vecs, self.host,
-            self.meter, self.ioe, CpuAdam(lr=ocfg.lr), ocfg.alpha,
-            param_dtype=np.dtype(ocfg.param_dtype))
+            self.meter, self.ioe, CpuAdam(lr=ocfg.lr), ocfg.alpha)
         self.act_c = ActivationCoordinator(x.act, self.host, self.ssd,
                                            self.meter, self.ioe)
         for c in self._coordinators():

@@ -322,8 +322,21 @@ class TieredVector:
             self.ssd.write_range(self.name + ":s", data[lo_s - lo:],
                                  lo_s - self.k, self.category)
 
+    def host_view(self, lo: int, hi: int) -> Optional[np.ndarray]:
+        """The host tier's elements [lo, hi) as a view, which an
+        in-place update changes with no copy; None when the range
+        touches the SSD share (use :meth:`read_range` and
+        :meth:`write_seg` there)."""
+        if hi > self.k or not self.k:
+            return None
+        return self.host.get(self.name + ":h")[lo:hi]
+
     def read_range(self, lo: int, hi: int, out: Optional[np.ndarray] = None
                    ) -> np.ndarray:
+        """A copy of elements [lo, hi), into ``out`` or a fresh array:
+        the host share is copied, the SSD share read (metered). An
+        update of the copy reaches the vector only through
+        :meth:`write_seg`."""
         if out is None:
             out = np.empty((hi - lo,), self.dtype)
         if lo < self.k:
