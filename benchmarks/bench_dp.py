@@ -39,7 +39,7 @@ from common import Reporter  # noqa: E402
 from repro.core.perfmodel import (MachineParams, StorageRatios, Workload,
                                   iteration_time_vertical_dp)
 from repro.io import IOConfig, IOEngine, IOPriority
-from repro.offload.dp import shard_bounds
+from repro.core.plan import shard_bounds
 from repro.offload.stores import SSDStore, TrafficMeter
 
 

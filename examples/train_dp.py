@@ -7,7 +7,7 @@ sets, ZeRO-style sharded optimizer state, deterministic collectives.
 Each rank owns a contiguous 1/R element range of every tiered vector
 (low-precision params, master, momentum, variance) on its OWN I/O
 engine + SSD directory set, all-gathers params per layer boundary and
-reduce-scatters layer gradients — see `repro.offload.dp`. With
+reduce-scatters layer gradients — see `repro.offload.engine`. With
 ``--verify-single-rank`` the same seed/batches are replayed on the
 single-rank engine and the per-step losses are compared bit-for-bit
 (they must be identical in f32, §6.5 extended across the DP axis).
@@ -27,8 +27,7 @@ from repro.configs import get_config
 from repro.core.perfmodel import StorageRatios
 from repro.core.traffic import dp_vertical_traffic
 from repro.data import SyntheticLM
-from repro.offload import (DataParallelOffloadEngine, IOConfig,
-                           OffloadConfig, OffloadEngine)
+from repro.offload import IOConfig, OffloadConfig, make_engine
 
 
 def main() -> None:
@@ -57,12 +56,12 @@ def main() -> None:
     with tempfile.TemporaryDirectory(prefix="greedysnake_dp_") as root:
         paths = [os.path.join(root, f"nvme{i}")
                  for i in range(R * args.paths_per_rank)]
-        eng = DataParallelOffloadEngine(
+        eng = make_engine(
             cfg, OffloadConfig(io=IOConfig(paths=paths, bandwidth=bandwidth),
                                **ocfg_kw),
-            jax.random.PRNGKey(0), root, ranks=R)
+            jax.random.PRNGKey(0), root, num_ranks=R)
         print(f"{R} ranks × {args.paths_per_rank} path(s) each; "
-              f"shard bounds {eng.bounds} of P={eng.P} per layer")
+              f"shard bounds {eng.bounds[0]} of P={eng.P} per layer")
         data = SyntheticLM(cfg.vocab_size, seed=0)
         t0 = time.perf_counter()
         losses = []
@@ -96,8 +95,8 @@ def main() -> None:
         if args.verify_single_rank:
             print("\nreplaying on the single-rank engine ...")
             with tempfile.TemporaryDirectory() as d1:
-                ref = OffloadEngine(cfg, OffloadConfig(**ocfg_kw),
-                                    jax.random.PRNGKey(0), d1)
+                ref = make_engine(cfg, OffloadConfig(**ocfg_kw),
+                                  jax.random.PRNGKey(0), d1)
                 data = SyntheticLM(cfg.vocab_size, seed=0)
                 ref_losses = [ref.train_step(data.batch(M * mb, args.seq))
                               for _ in range(args.steps)]

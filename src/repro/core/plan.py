@@ -658,22 +658,18 @@ class PlanCosts:
 
     @staticmethod
     def from_engine(eng) -> "PlanCosts":
-        """Sizing facts read off a live (single-rank or DP) engine."""
+        """Sizing facts read off a live ``OffloadEngine``."""
         ocfg = eng.ocfg
         item = eng.dtype.itemsize
         head_nbytes = 4 * (eng.embed.size + eng.unembed.size
                            + eng.final_norm.size)
-        # the single-rank engine sizes every layer; the DP engine's
-        # layers are all P
-        sizes = tuple(getattr(eng, "layer_P", ()))
-        acts = tuple(getattr(eng, "layer_act_nbytes", ()))
         return PlanCosts(
-            P=sizes[0] if sizes else eng.P, param_itemsize=item,
+            P=eng.layer_P[0], param_itemsize=item,
             ckpt_elems=ocfg.micro_batch * ocfg.seq_len * eng.cfg.d_model,
             act_itemsize=item, ratios=ocfg.ratios, alpha=ocfg.alpha,
-            ranks=getattr(eng, "R", 1), head_nbytes=head_nbytes,
-            act_res_bytes=acts[0] if acts else getattr(eng, "act_nbytes", 0),
-            layer_P=sizes, layer_act_res_bytes=acts)
+            ranks=eng.R, head_nbytes=head_nbytes,
+            act_res_bytes=eng.layer_act_nbytes[0],
+            layer_P=eng.layer_P, layer_act_res_bytes=eng.layer_act_nbytes)
 
 
 def _khost(x: float, n: int) -> int:
@@ -716,12 +712,8 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
     a = costs.act_itemsize
     u = E * a                                   # one boundary tensor
     ps = costs.param_itemsize
-    if costs.layer_P and R > 1:
-        raise ValueError("per-layer sizes are for single-rank plans; the "
-                         "DP engine shards one size P")
     kc = _khost(x.ckpt, E)
     Mr = plan.spec.M // R
-    bounds = shard_bounds(costs.P, R)
     out = [defaultdict(int) for _ in range(R)]
 
     def owner(m: int) -> int:
@@ -746,9 +738,8 @@ def plan_traffic(plan: Plan, costs: PlanCosts):
     for op in plan.ops:
         k = op.op
         if k in _PARAM_SIZED:
-            # per-layer sizes are single-rank: layer l is one shard [0, P_l)
             P = costs.P_of(op.l)
-            bounds_l = [(0, P)] if costs.layer_P else bounds
+            bounds_l = shard_bounds(P, R)
         if k is Op.FETCH_PARAM:
             if costs.param_unit_nbytes:
                 # serving: per-unit param blob, tiered by byte fraction

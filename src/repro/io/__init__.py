@@ -176,9 +176,9 @@ least two request workers.
 Per-rank engine layering (data parallelism)
 ===========================================
 
-The data-parallel offload engine (``repro.offload.dp``) instantiates
-the WHOLE stack above once per rank: rank r gets its own ``IOEngine``
-over its own path subset (:meth:`~repro.io.config.IOConfig.
+An offload engine on R > 1 data-parallel ranks
+(``repro.offload.engine``) instantiates the WHOLE stack above once per
+rank: rank r gets its own ``IOEngine`` over its own path subset (:meth:`~repro.io.config.IOConfig.
 shard_for_rank`: paths ``r, r+R, ...``, with the matching
 ``path_bandwidth`` caps sliced alongside so a rank's placement policy
 sees its own devices' rates), its own meter/host/staging state, and
@@ -191,7 +191,7 @@ Rank-sharding invariants the test battery pins down
 (``tests/test_dp_offload.py``, ``tests/test_property.py``):
 
 * every tiered vector is split into CONTIGUOUS element ranges covering
-  [0, P) (``repro.offload.dp.shard_bounds``) — elementwise ops (Adam,
+  [0, P) (``repro.core.plan.shard_bounds``) — elementwise ops (Adam,
   gradient accumulation) commute bitwise with the split;
 * collectives fold contributions in GLOBAL micro-batch order, so an
   R-rank run is bit-identical (f32) to the single-rank engine;

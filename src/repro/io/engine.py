@@ -308,8 +308,9 @@ class IOEngine:
                  default_root: Optional[str] = None, tracer=None,
                  label: str = ""):
         # ``tracer``: a shared repro.obs.Tracer (or None); ``label``
-        # prefixes the worker thread names — the DP engine passes
-        # "rank<r>-" so each rank's channels get distinct trace tracks.
+        # prefixes the worker thread names — an offload engine on R > 1
+        # ranks passes "rank<r>-" so each rank's channels get distinct
+        # trace tracks.
         # The default is built HERE, not in the signature: a default
         # argument is evaluated once at class-definition time, so
         # `config: IOConfig = IOConfig()` would hand every
@@ -351,7 +352,8 @@ class IOEngine:
         # the O(1) backlog signal the adaptive lookahead polls per hint
         # (depth() reports the same numbers without scanning heaps) —
         # plus the per-path counterparts (chunk backlog, cumulative
-        # bytes/ops) that depth()/stats() report for path-level pacing
+        # bytes/ops) that depth()/metrics_snapshot() report for
+        # path-level pacing
         self._backlog_lock = threading.Lock()
         self._route_backlog: Dict[str, int] = {}
         self._path_backlog = [0] * len(self.paths)
@@ -762,16 +764,6 @@ class IOEngine:
         ``version`` key tracking ``repro.obs.SNAPSHOT_VERSION``)."""
         from repro.obs.registry import SNAPSHOT_VERSION
         return {"version": SNAPSHOT_VERSION, **self._collect_stats()}
-
-    def stats(self) -> dict:
-        """Deprecated alias for :func:`metrics_snapshot` (without the
-        ``version`` key). Will be removed after the deprecation window
-        noted in CHANGES.md."""
-        import warnings
-        warnings.warn(
-            "IOEngine.stats() is deprecated; use metrics_snapshot()",
-            DeprecationWarning, stacklevel=2)
-        return self._collect_stats()
 
     # ---------------- lifecycle ----------------
     def shutdown(self, wait: bool = True):

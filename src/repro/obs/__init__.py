@@ -16,8 +16,8 @@ Three pieces, layered bottom-up:
   ``export_chrome(path)`` writes Perfetto-loadable Chrome trace-event
   JSON.
 * ``build_snapshot`` (``obs.registry``) — the versioned flat
-  ``metrics_snapshot()`` both engines expose: one JSON-serializable
-  dict subsuming ``stats()``, embedding ``plan_costs`` and the trace's
+  ``metrics_snapshot()`` of the offload engine: one JSON-serializable
+  dict embedding ``plan_costs`` and the trace's
   per-route aggregates. This schema is the ingestion contract for the
   ROADMAP item-3 autotuner.
 * :func:`reconcile` (``obs.reconcile``) — joins a snapshot against

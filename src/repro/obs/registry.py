@@ -1,13 +1,12 @@
 """The unified metrics registry: one versioned, flat, JSON-serializable
 snapshot of everything the offload stack measures.
 
-``build_snapshot(eng)`` works on both :class:`OffloadEngine` and
-:class:`DataParallelOffloadEngine` (both expose it as
-``metrics_snapshot()``) and SUBSUMES their ``stats()`` shapes — every
-``stats()`` field appears here, normalized to per-rank lists so the
-single-rank and DP schemas are the same shape. The dict round-trips
-through ``json.dumps`` by construction (numpy ints coerced, tuples
-listed): it is the artifact the bench-smoke job persists and the
+``build_snapshot(eng)`` reads an :class:`OffloadEngine` on any rank
+count (exposed as ``metrics_snapshot()``); per-rank fields are lists
+indexed by rank, so every rank count has the same schema. The dict
+round-trips through ``json.dumps`` by construction (numpy ints
+coerced, tuples listed): it is the artifact the bench-smoke job
+persists and the
 ingestion contract for the ROADMAP item-3 autotuner, which is why the
 schema carries ``version`` (bump ``SNAPSHOT_VERSION`` on any breaking
 shape change) and embeds ``plan_costs`` — enough to re-run
@@ -21,15 +20,9 @@ from typing import Dict, List
 
 #: Bump on any breaking change to the snapshot shape. Consumers
 #: (``obs.reconcile``, ``check_smoke.py``, the future autotuner) must
-#: check this before reading.
-SNAPSHOT_VERSION = 1
-
-
-def _rank_stacks(eng) -> list:
-    """Per-rank stacks: the DP engine's ``ranks`` list, or the
-    single-rank engine itself (same attribute surface)."""
-    rks = getattr(eng, "ranks", None)
-    return list(rks) if rks is not None else [eng]
+#: check this before reading. 2: ``bounds`` is per layer at every
+#: rank count.
+SNAPSHOT_VERSION = 2
 
 
 def _jsonable(obj):
@@ -60,10 +53,10 @@ def build_snapshot(eng) -> Dict[str, object]:
       bytes`` meter snapshots, the measured side of the reconciliation)
     * storage — ``io`` / ``io_depth`` (per-rank ``IOEngine.metrics_snapshot()`` /
       ``depth()``, including the per-path counters),
-      ``host_peak_nbytes`` / ``host_nbytes``, ``bounds`` (DP shard
-      ranges, ``None`` single-rank)
+      ``host_peak_nbytes`` / ``host_nbytes``, ``bounds`` (per layer,
+      each rank's element range; one range covering the layer at R = 1)
     * time — ``op_seconds``, ``stall_s``, ``phase_time``
-    * lookahead — ``lookahead`` (the ``lookahead_stats`` shape),
+    * lookahead — ``lookahead`` (``OffloadEngine._lookahead_stats()``),
       ``hint_skips`` / ``act_skips`` / ``act_fallbacks``
     * host optimizer — ``opt_inplace_elems`` / ``opt_staged_elems``
       (per-rank master/m/v elements updated through host views and
@@ -76,16 +69,13 @@ def build_snapshot(eng) -> Dict[str, object]:
     from repro.core.plan import PlanCosts
     from repro.offload.executor import stall_seconds
 
-    rks = _rank_stacks(eng)
+    rks = eng.ranks
     costs = dataclasses.asdict(PlanCosts.from_engine(eng))
-    tracer = getattr(eng, "tracer", None)
-    trace = tracer.summary() if tracer is not None else \
-        {"enabled": False, "spans": 0, "dropped": 0, "routes": {}}
     lookahead = eng._lookahead_stats()
     snap = {
         "version": SNAPSHOT_VERSION,
         "schedule": eng.ocfg.schedule,
-        "ranks": int(getattr(eng, "R", 1)),
+        "ranks": int(eng.R),
         "steps": int(eng.step_num),
         "act_policy": eng.act_policy,
         "traffic": [dict(rk.meter.snapshot()) for rk in rks],
@@ -93,7 +83,7 @@ def build_snapshot(eng) -> Dict[str, object]:
         "io_depth": [rk.ioe.depth() for rk in rks],
         "host_peak_nbytes": [rk.host.peak_nbytes for rk in rks],
         "host_nbytes": [rk.host.nbytes() for rk in rks],
-        "bounds": getattr(eng, "bounds", None),
+        "bounds": eng.bounds,
         "op_seconds": dict(eng.op_seconds),
         "stall_s": stall_seconds(eng.op_seconds),
         "phase_time": dict(eng.phase_time),
@@ -104,7 +94,7 @@ def build_snapshot(eng) -> Dict[str, object]:
         "opt_inplace_elems": [rk.opt_c.inplace_elems for rk in rks],
         "opt_staged_elems": [rk.opt_c.staged_elems for rk in rks],
         "plan_costs": costs,
-        "trace": trace,
+        "trace": eng.tracer.summary(),
     }
     # additive: the online autotuner's decision log (attached by
     # repro.offload.autotune.AutotuneController) rides along so a

@@ -119,8 +119,8 @@ class AutotuneConfig:
 
 
 class AutotuneController:
-    """Drives the measure → solve → swap loop for one engine (either
-    ``OffloadEngine`` or ``DataParallelOffloadEngine``).
+    """Drives the measure → solve → swap loop for one
+    ``OffloadEngine`` (any rank count).
 
     Usage::
 
@@ -148,15 +148,11 @@ class AutotuneController:
         self._begin_window()
 
     # ---------------- window machinery ----------------
-    def _ranks(self):
-        return self.eng.ranks if hasattr(self.eng, "ranks") \
-            else (self.eng,)
-
     def _begin_window(self):
         """Zero every per-window meter so the next snapshot describes
         ONLY this window (the byte counters feed reconcile; the span
         ring feeds machine_from_snapshot)."""
-        for rk in self._ranks():
+        for rk in self.eng.ranks:
             rk.meter.reset()
         self.eng.reset_stats()
         self.eng.tracer.clear()
@@ -196,18 +192,17 @@ class AutotuneController:
         return (ocfg.resolved_wave_size(),
                 ocfg.resolved_prefetch_depth(),
                 self.eng.act_policy,
-                self._ranks()[0].ioe.path_policy)
+                self.eng.ranks[0].ioe.path_policy)
 
     def _candidates(self) -> List[Tuple[int, int, str, str]]:
         """The candidate knob product. Axes not configured stay at
         their current value; wave candidates must divide M and are
-        dropped under DP (DP plans are vertical — ``solve_config``
-        rejects a wave there for the same reason)."""
+        dropped when R > 1 (data-parallel plans are vertical —
+        ``solve_config`` rejects a wave there for the same reason)."""
         a = self.acfg
         W_cur, d_cur, pol_cur, pp_cur = self._current_knobs()
         M = self.eng.ocfg.num_microbatches
-        dp = hasattr(self.eng, "ranks")
-        waves = [W_cur] if (a.wave_sizes is None or dp) else \
+        waves = [W_cur] if (a.wave_sizes is None or self.eng.R > 1) else \
             [int(w) for w in a.wave_sizes if 0 < int(w) <= M
              and M % int(w) == 0]
         depths = [d_cur] if a.prefetch_depths is None else \
@@ -236,11 +231,9 @@ class AutotuneController:
         ``solve_config`` raises ``ValueError`` on the rest."""
         eng = self.eng
         W, depth, pol, path_pol = knobs
-        R = getattr(eng, "R", 1)
-        w = engine_workload(eng.ocfg, eng.cfg,
-                            getattr(eng, "layer_P", eng.P),
-                            eng.dtype.itemsize,
-                            getattr(eng, "layer_act_nbytes", eng.act_nbytes))
+        R = eng.R
+        w = engine_workload(eng.ocfg, eng.cfg, eng.layer_P,
+                            eng.dtype.itemsize, eng.layer_act_nbytes)
         sol = solve_config(machine, w, eng.ocfg.num_microbatches,
                            eng.ocfg.alpha, num_gpus=R,
                            wave=None if R > 1 else W,
@@ -341,7 +334,7 @@ class AutotuneController:
         the "backlog" placement policy consumes per chunk — see the
         module header)."""
         out = []
-        for rk in self._ranks():
+        for rk in self.eng.ranks:
             ioe = rk.ioe
             out.append({"least_loaded_path": ioe.least_loaded_path(),
                         "imbalance": ioe.path_imbalance(),

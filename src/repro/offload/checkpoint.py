@@ -1,15 +1,15 @@
-"""Crash-consistent training checkpoints for the offload engines.
+"""Crash-consistent training checkpoints for the offload engine.
 
 The checkpoint is the engine's FULL trainable state — per layer the
 low-precision params and the f32 master/m/v optimizer vectors, plus the
 device-resident embedding/head tensors, their Adam state, and
 ``step_num`` — exactly the state whose round-trip the plan-swap
 bitwise pin established (``tests/test_autotune.py`` grew it ad hoc;
-this module is its promotion). Vectors are stored ASSEMBLED (full
-``P``-element vectors, not rank shards), so a checkpoint written by the
-single-rank engine restores into the DP engine and vice versa: DP
-sharding is contiguous (``shard_bounds``), so assembly is
-concatenation and restore is slicing — both bitwise.
+this module is its promotion). Vectors are stored ASSEMBLED (each
+layer's full vector, not rank shards), so a checkpoint written on one
+rank count restores on any other: sharding is contiguous
+(``eng.bounds[l]``, one range covering the layer at R = 1), so assembly
+is concatenation and restore is slicing — both bitwise.
 
 Crash consistency is manifest-journaled:
 
@@ -57,19 +57,13 @@ def _fname(name: str, gen: int) -> str:
     return name.replace(":", "_").replace("/", "_") + f".g{gen}.bin"
 
 
-def _is_dp(eng) -> bool:
-    return hasattr(eng, "ranks")
-
-
-def _assemble(eng, attr: str, l: int, dtype) -> np.ndarray:
+def assemble(eng, attr: str, l: int, dtype) -> np.ndarray:
     """Layer ``l``'s full vector from ``attr`` (``p_vecs``/``m_master``/
-    ``m_m``/``m_v``), concatenating rank shards on the DP engine."""
-    if _is_dp(eng):
-        out = np.empty(eng.P, dtype)
-        for rk, (lo, hi) in zip(eng.ranks, eng.bounds):
-            out[lo:hi] = getattr(rk, attr)[l].read()
-        return out
-    return np.asarray(getattr(eng, attr)[l].read(), dtype).copy()
+    ``m_m``/``m_v``), concatenating the rank shards."""
+    out = np.empty(eng.layer_P[l], dtype)
+    for rk, (lo, hi) in zip(eng.ranks, eng.bounds[l]):
+        out[lo:hi] = getattr(rk, attr)[l].read()
+    return out
 
 
 def _p_meta(eng):
@@ -89,7 +83,7 @@ def _state_items(eng) -> Iterator[Tuple[str, np.ndarray]]:
     for l in range(eng.L):
         for key, attr in _VEC_ATTRS:
             dt = pdt if key == "p" else np.float32
-            yield f"{key}:{l}", _assemble(eng, attr, l, dt)
+            yield f"{key}:{l}", assemble(eng, attr, l, dt)
     for t in _HEAD_TENSORS:
         yield t, np.asarray(eng.__dict__[t])
         for k in ("m", "v"):
@@ -115,13 +109,9 @@ def _quiesce(eng):
         eng.finish()
     except Exception:
         pass
-    stacks = eng.ranks if _is_dp(eng) else (eng,)
-    for s in stacks:
-        s.params_c.reset()
-        s.params_c.clear_gates()
-        s.ckpt_c.clear()
-        s.act_c.clear()
-        s.opt_c.clear()
+    for rk in eng.ranks:
+        rk.clear()
+        rk.opt_c.clear()
 
 
 def save_checkpoint(eng, directory: str) -> str:
@@ -148,7 +138,7 @@ def save_checkpoint(eng, directory: str) -> str:
            "meta": {"L": int(eng.L), "P": _p_meta(eng), "step_num": gen,
                     "param_dtype": str(np.dtype(eng.ocfg.param_dtype)),
                     "arch": getattr(eng.cfg, "name", ""),
-                    "ranks": int(getattr(eng, "R", 1))},
+                    "ranks": int(eng.R)},
            "tensors": tensors}
     target = os.path.join(directory, MANIFEST)
     tmp = target + ".tmp"
@@ -234,15 +224,11 @@ def restore_checkpoint(eng, directory: str) -> int:
     # everything verified — now (and only now) mutate the engine
     import jax.numpy as jnp
     _quiesce(eng)
-    dp = _is_dp(eng)
     for l in range(eng.L):
         for key, attr in _VEC_ATTRS:
             arr = arrays[f"{key}:{l}"]
-            if dp:
-                for rk, (lo, hi) in zip(eng.ranks, eng.bounds):
-                    getattr(rk, attr)[l].write_full(arr[lo:hi])
-            else:
-                getattr(eng, attr)[l].write_full(arr)
+            for rk, (lo, hi) in zip(eng.ranks, eng.bounds[l]):
+                getattr(rk, attr)[l].write_full(arr[lo:hi])
     for t in _HEAD_TENSORS:
         setattr(eng, t, jnp.asarray(arrays[t]))
     eng.head_state = {t: {k: jnp.asarray(arrays[f"head:{t}:{k}"])

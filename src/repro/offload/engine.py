@@ -1,6 +1,6 @@
 """OffloadEngine: GreedySnake's schedules executed against REAL
 three-tier storage, by compiling a schedule plan once and interpreting
-it every step.
+it every step — on one rank or on R data-parallel ranks.
 
 Design note (the schedule IR)
 =============================
@@ -12,9 +12,8 @@ This engine no longer hard-codes any schedule as control flow. Instead:
   ``SPILL_CKPT``/``FETCH_CKPT``, ``BWD``, ``WRITEBACK_GRAD``,
   ``OPT_LATE``, ... — see the op table in that module), with
   ``PREFETCH`` hints derived by a lookahead pass;
-* ``repro.offload.executor.execute_plan`` — the ONE executor, shared
-  with the data-parallel engine — walks the plan against the three
-  coordinators and the ``repro.io`` engine;
+* ``repro.offload.executor.execute_plan`` — the ONE executor — walks
+  the plan against each rank's coordinators and ``repro.io`` engine;
 * ``repro.core.plan.plan_traffic`` predicts every byte counter of a
   run statically from the same IR, cross-checked exactly against the
   closed forms in ``repro.core.traffic`` AND the engine's measured
@@ -49,15 +48,42 @@ re-reads; ``"auto"`` prices both with ``repro.core.perfmodel`` against
 policies apply the SAME saved-residual backward, so they are
 bitwise-identical (f32) in losses and parameters; the closed forms are
 ``repro.core.traffic.act_spill_traffic`` + the ``act_spill=True`` ckpt
-variants, and ``A`` is sized exactly by :func:`act_residual_nbytes`.
+variants, and ``A`` is sized exactly by ``jax.eval_shape``.
 
 The embedding and LM head stay device-resident (the paper excludes them
 from the per-layer pipeline and adds their time separately, §4.5).
+
+Ranks (data parallelism)
+========================
+
+ZeRO-style partitioned offload, the layout GreedySnake's multi-GPU
+baseline uses. The engine holds ``ranks``: R stacks, each with its own meter, host
+store, ``IOEngine`` + SSD path set (``IOConfig.shard_for_rank``), the
+contiguous shard ``bounds[l][r]`` of every layer's tiered vectors
+(params, master, momentum, variance) and the four coordinators over
+that shard. R = 1 is the single-rank engine: one stack rooted at the
+workdir itself, whose fields are also the engine's (``eng.meter``,
+``eng.p_vecs``, ...). R > 1 runs ONE compiled vertical plan with
+``ALLGATHER`` / ``REDUCE_SCATTER`` in place of ``FETCH_PARAM`` /
+``WRITEBACK_GRAD``; rank ``r`` owns micro-batches ``[r·Mr, (r+1)·Mr)``,
+its stack lives under ``<workdir>/rank<r>`` and its I/O threads carry
+the ``rank<r>-`` label.
+
+The collectives are simulated on one device and deterministic: the
+reduce-scatter folds the per-micro-batch layer gradients in GLOBAL
+micro-batch order — the single-rank fold — and every elementwise op
+(accumulation, Adam) commutes bitwise with element-range slicing, so an
+R-rank run is bit-identical (f32) to R = 1. Collective bytes are
+metered per rank on routes ``"gpu->net"`` / ``"net->gpu"`` at ring cost,
+(R-1)/R of the buffer (``"param"`` all-gather, ``"grad"``
+reduce-scatter, ``"head_grad"`` for the replicated embedding/head
+all-reduce, §4.5); the closed forms are
+:func:`repro.core.traffic.dp_vertical_traffic`.
 """
 from __future__ import annotations
 
 import dataclasses
-import warnings
+import os
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
@@ -67,7 +93,7 @@ import numpy as np
 
 from repro.core.perfmodel import MachineParams, StorageRatios
 from repro.core.plan import (PlanSpec, compile_wave, insert_prefetch,
-                             mb_order)
+                             mb_order, shard_bounds)
 from repro.io import IOConfig, IOEngine
 from repro.io.config import PATH_POLICIES
 from repro.models import blocks as blk
@@ -78,15 +104,12 @@ from repro.offload.coordinators import (ActivationCoordinator,
                                         OptimizerStepCoordinator,
                                         ParameterCoordinator)
 from repro.obs.tracer import CAT_COUNTER
-from repro.offload.executor import LayerFns, execute_plan
+from repro.offload.executor import LayerFns, execute_plan, stall_seconds
 from repro.offload.stores import HostStore, SSDStore, TieredVector, TrafficMeter
 from repro.optim.cpu_adam import CpuAdam
 
 __all__ = ["OffloadConfig", "OffloadEngine", "build_block_fns",
-           "build_layer_fns", "kind_label",
-           "bind_block_fns", "mb_order", "split_microbatches",
-           "shifted_labels", "act_residual_nbytes",
-           "resolve_activation_policy", "engine_workload"]
+           "build_layer_fns", "kind_label", "mb_order", "engine_workload"]
 
 
 @dataclasses.dataclass
@@ -279,11 +302,10 @@ def build_layer_fns(cfg, kind, unflatten, suffix: str = ""
 
 def build_block_fns(cfg, kind, unflatten, suffix: str = ""
                     ) -> Dict[str, object]:
-    """Jitted per-layer / embedding / head functions, shared by the
-    single-rank and data-parallel engines. Both engines driving the SAME
-    compiled computations is what makes an R-rank run bit-identical
-    (f32) to a single-rank run — any per-engine recompilation could
-    legally re-fuse and break that."""
+    """Jitted per-layer / embedding / head functions. Every rank count
+    drives the SAME compiled computations, which is what makes an
+    R-rank run bit-identical (f32) to a single-rank run — a per-R
+    recompilation could legally re-fuse and break that."""
 
     def embed_fwd(embed, tokens):
         return embed[tokens]
@@ -314,24 +336,11 @@ def build_block_fns(cfg, kind, unflatten, suffix: str = ""
     }
 
 
-def bind_block_fns(obj, fns: Dict[str, object]) -> None:
-    """Attach :func:`build_block_fns` results as the ``j_*`` attributes
-    both engines use."""
-    obj.j_layer_fwd = fns["layer_fwd"]
-    obj.j_layer_fwd_res = fns["layer_fwd_res"]
-    obj.j_layer_bwd_res = fns["layer_bwd_res"]
-    obj.j_embed = fns["embed"]
-    obj.j_head_bwd = fns["head_bwd"]
-    obj.j_embed_bwd = fns["embed_bwd"]
-    obj.j_adam_dev = fns["adam_dev"]
-
-
-def act_residual_nbytes(j_layer_fwd_res, P: int, dtype, micro_batch: int,
-                        seq_len: int, d_model: int) -> int:
+def _act_residual_nbytes(j_layer_fwd_res, P: int, dtype, micro_batch: int,
+                         seq_len: int, d_model: int) -> int:
     """EXACT byte size of one (layer, micro-batch) residual payload —
     what each ``SPILL_ACT``/``FETCH_ACT`` moves — via ``jax.eval_shape``
-    (no compute, no allocation). Shared by both engines and by
-    ``PlanCosts.from_engine`` through the ``act_nbytes`` attribute."""
+    (no compute, no allocation)."""
     res = jax.eval_shape(
         j_layer_fwd_res,
         jax.ShapeDtypeStruct((P,), dtype),
@@ -340,191 +349,203 @@ def act_residual_nbytes(j_layer_fwd_res, P: int, dtype, micro_batch: int,
                for leaf in jax.tree.leaves(res))
 
 
-def resolve_activation_policy(ocfg: OffloadConfig, cfg, P, itemsize: int,
-                              act_nbytes) -> str:
-    """Resolve the ``activation_policy`` knob to "recompute"|"spill".
-    "auto" prices both policies with the perf model
-    (:func:`repro.core.perfmodel.pick_activation_policy`) using
-    ENGINE-accurate workload bytes (this engine's dtype and measured
-    residual size, not the bf16 paper defaults) and the machine from
-    ``ocfg.machine``, the configured bandwidth caps, or the defaults.
-    """
-    pol = ocfg.activation_policy
-    if pol in ("recompute", "spill"):
-        return pol
-    if pol != "auto":
-        raise ValueError(f"unknown activation_policy {pol!r}")
-    from repro.core.perfmodel import (machine_from_bandwidth,
-                                      pick_activation_policy)
-    m = ocfg.machine
-    if m is None:
-        bw = ocfg.io.bandwidth if ocfg.io is not None else None
-        m = machine_from_bandwidth(bw) if bw else MachineParams()
-    w = engine_workload(ocfg, cfg, P, itemsize, act_nbytes)
-    M = ocfg.num_microbatches
-    return pick_activation_policy(w, m, M, ocfg.resolved_wave_size(),
-                                  ocfg.alpha, ocfg.ratios,
-                                  lookahead=ocfg.resolved_prefetch_depth()
-                                  > 0)
-
-
-def _per_layer(x, L: int) -> List[int]:
-    """A per-layer size given as one int (every layer alike) or as a
-    sequence of L."""
-    return [int(x)] * L if np.ndim(x) == 0 else [int(v) for v in x]
-
-
-def engine_workload(ocfg: OffloadConfig, cfg, P, itemsize: int,
-                    act_nbytes):
+def engine_workload(ocfg: OffloadConfig, cfg, layer_P, itemsize: int,
+                    layer_act_nbytes):
     """The ENGINE-accurate :class:`repro.core.perfmodel.Workload`: the
     FLOP model comes from the one place it is maintained
     (``Workload.from_config``); only the byte fields are overridden
     with this engine's actual sizes — its dtype, its flat layer
-    vectors, its measured residual payloads. ``P`` and ``act_nbytes``
-    are one size for every layer or one per layer. The one workload
+    vectors (``layer_P``), its measured residual payloads
+    (``layer_act_nbytes``), one of each per layer. The one workload
     both the "auto" activation-policy pricing and the online autotuner
     solve against (an autotuner solving the paper's bf16 defaults would
     retune the wrong machine)."""
     from repro.core.perfmodel import Workload
     L = cfg.num_layers
     tokens = ocfg.micro_batch * ocfg.seq_len
-    n = sum(_per_layer(P, L))
+    n = sum(layer_P)
     return dataclasses.replace(
         Workload.from_config(cfg, ocfg.micro_batch, ocfg.seq_len),
         ms=n * itemsize,
         cs=L * tokens * cfg.d_model * itemsize,
         os_bytes=3 * n * 4,
         grad_bytes=n * 4,
-        as_bytes=sum(_per_layer(act_nbytes, L)),
+        as_bytes=sum(layer_act_nbytes),
     )
 
 
-def lookahead_stats(eng, coordinators) -> Dict[str, object]:
-    """Prefetch hit/miss counters aggregated over ``coordinators`` plus
-    the engine's adaptive-skip counters and per-op stall meters — the
-    ONE ``stats()["lookahead"]`` shape for both engines (the DP engine
-    passes every rank's coordinator stack)."""
-    from repro.offload.executor import stall_seconds
-    hits = sum(c.la_hits for c in coordinators)
-    misses = sum(c.la_misses for c in coordinators)
-    total = hits + misses
-    return {"hits": hits, "misses": misses,
-            "hit_rate": hits / total if total else 1.0,
-            "hint_skips": eng.hint_skips,
-            "act_skips": eng.act_skips,
-            "stall_s": stall_seconds(eng.op_seconds),
-            "op_seconds": dict(eng.op_seconds)}
+class _Rank:
+    """One rank's stack: its traffic meter, host store, ``IOEngine`` and
+    SSD store, its shard of every layer's tiered vectors (appended by
+    :meth:`add_layer`) and the four coordinators over that shard."""
 
+    def __init__(self, iocfg: IOConfig, root: str, label: str, tracer,
+                 ocfg: OffloadConfig):
+        x = ocfg.ratios
+        self.meter = TrafficMeter()
+        self.host = HostStore(self.meter)
+        # All of the rank's offload traffic flows through one IOEngine. A
+        # gated param fetch may wait on an optimizer request, and two
+        # fetches can be gated at once, so the engine needs at least 3
+        # request workers or the α-delay gate discipline can deadlock.
+        if iocfg.workers < 3:
+            iocfg = dataclasses.replace(iocfg, workers=3)
+        self.ioe = IOEngine(iocfg, meter=self.meter, default_root=root,
+                            tracer=tracer, label=label)
+        self.ssd = SSDStore(root, self.meter, engine=self.ioe)
+        self.p_vecs: List[TieredVector] = []
+        self.m_master: List[TieredVector] = []
+        self.m_m: List[TieredVector] = []
+        self.m_v: List[TieredVector] = []
+        self.params_c = ParameterCoordinator(self.p_vecs, self.meter,
+                                             self.ioe)
+        self.ckpt_c = InterLayerTensorCoordinator(x.ckpt, self.host, self.ssd,
+                                                  self.meter, self.ioe)
+        self.opt_c = OptimizerStepCoordinator(
+            self.m_master, self.m_m, self.m_v, self.p_vecs, self.host,
+            self.meter, self.ioe, CpuAdam(lr=ocfg.lr), ocfg.alpha)
+        self.act_c = ActivationCoordinator(x.act, self.host, self.ssd,
+                                           self.meter, self.ioe)
+        for c in self.coordinators():
+            c.tracer = tracer
 
-def reset_lookahead_stats(eng, coordinators) -> None:
-    """Zero EVERY measured-iteration meter — stall/phase timers,
-    adaptive-skip and fallback counters, lookahead hit/miss counts —
-    so a second measured iteration after reset reports exactly like the
-    first (bench warm-up boundary; traffic meters have their own
-    ``reset``, and the I/O engines' cumulative stats are lifetime
-    counters by design)."""
-    eng.op_seconds.clear()
-    eng.hint_skips = eng.act_skips = 0
-    eng.act_fallbacks = 0
-    for k in eng.phase_time:
-        eng.phase_time[k] = 0.0
-    for c in coordinators:
-        c.la_hits = c.la_misses = 0
+    def add_layer(self, l: int, params: np.ndarray, master: np.ndarray,
+                  ocfg: OffloadConfig) -> None:
+        """Persist this rank's shard of layer ``l``: the low-precision
+        params, their f32 master copy and zeroed Adam moments."""
+        x = ocfg.ratios
+        n = params.size
+        pv = TieredVector(f"param:{l}", n, ocfg.param_dtype, x.param,
+                          self.host, self.ssd, "param")
+        pv.write_full(params)
+        self.p_vecs.append(pv)
+        for name, lst, init in (("master", self.m_master, master),
+                                ("m", self.m_m, np.zeros(n, np.float32)),
+                                ("v", self.m_v, np.zeros(n, np.float32))):
+            tv = TieredVector(f"{name}:{l}", n, np.float32, x.opt,
+                              self.host, self.ssd, "opt")
+            tv.write_full(init)
+            lst.append(tv)
 
+    def coordinators(self):
+        return (self.params_c, self.ckpt_c, self.act_c, self.opt_c)
 
-def split_microbatches(tokens: np.ndarray, M: int, micro_batch: int
-                       ) -> np.ndarray:
-    assert tokens.shape[0] == M * micro_batch
-    return tokens.reshape(M, micro_batch, -1)
+    def finish(self, L: int, step: int) -> None:
+        for l in range(L):
+            self.opt_c.flush_late(l, step)
+            self.opt_c.wait_late(l)
+        self.opt_c.wait_all()
+        self.ckpt_c.wait_pending()
+        self.act_c.wait_pending()
 
+    def clear(self) -> None:
+        """Drop per-plan residue: cancel param prefetches, disarm the α
+        gates, clear the checkpoint and activation state."""
+        self.params_c.reset()
+        self.params_c.clear_gates()
+        self.ckpt_c.clear()
+        self.act_c.clear()
 
-def shifted_labels(tok_mb: np.ndarray):
-    """Next-token labels/weights for one micro-batch (last position
-    masked), identical across engines."""
-    lab = np.concatenate([tok_mb[:, 1:], np.zeros((tok_mb.shape[0], 1),
-                                                  tok_mb.dtype)], 1)
-    w = np.ones(tok_mb.shape, np.float32)
-    w[:, -1] = 0.0
-    return jnp.asarray(lab), jnp.asarray(w)
+    def close(self) -> None:
+        self.params_c.reset()
+        self.ckpt_c.wait_pending()
+        self.act_c.wait_pending()
+        self.opt_c.wait_all()
+        self.ssd.close()              # removes stripe files from the paths
+        self.ioe.shutdown(wait=True)
 
 
 class OffloadEngine:
+    """The offload trainer on ``ranks`` data-parallel ranks (see the
+    module docstring). R > 1 runs the vertical schedule over a stack of
+    one dense layer kind, with the micro-batches split evenly."""
+
     #: families whose layers are plain decoder blocks on one token stream
     FAMILIES = ("dense", "moe")
 
-    def __init__(self, cfg, ocfg: OffloadConfig, key, workdir: str):
+    def __init__(self, cfg, ocfg: OffloadConfig, key, workdir: str,
+                 ranks: int = 1):
         if cfg.family not in self.FAMILIES:
             raise ValueError(
                 f"the offload engine drives {self.FAMILIES} stacks, not "
                 f"family {cfg.family!r}")
-        self.cfg = cfg
-        self.ocfg = ocfg
         # the layer plan's prefix + periods + suffix, one kind per layer
         # (e.g. DeepSeek's leading dense layer, then MoE layers)
         self.layer_kinds = tuple(blk.build_plan(cfg).all_kinds())
         self.kinds = tuple(dict.fromkeys(self.layer_kinds))
+        M = ocfg.num_microbatches
+        if ranks > 1:
+            # refused before any file or thread exists
+            if cfg.family != "dense" or len(self.kinds) != 1:
+                raise ValueError(
+                    f"the data-parallel offload engine drives stacks of one "
+                    f"dense layer kind; {cfg.name!r} (family {cfg.family!r}) "
+                    "has other kinds — run it with num_ranks=1")
+            if ocfg.schedule != "vertical":
+                raise ValueError(
+                    "data-parallel offload runs the vertical schedule, not "
+                    f"{ocfg.schedule!r}")
+            if M % ranks:
+                raise ValueError(
+                    f"num_microbatches={M} must divide evenly across "
+                    f"{ranks} ranks (uneven sharding is a ROADMAP follow-on)")
+        self.cfg = cfg
+        self.ocfg = ocfg
         self.kind = self.kinds[0]
         self.L = cfg.num_layers
+        self.R = ranks
+        self.Mr = M // ranks            # micro-batches each rank owns
         self.dtype = jnp.dtype(ocfg.param_dtype)
-        self.meter = TrafficMeter()
-        self.host = HostStore(self.meter)
-        # All offload traffic flows through one IOEngine. A gated param
-        # fetch may wait on an optimizer request, and two fetches can be
-        # gated at once, so the engine needs at least 3 request workers
-        # or the α-delay gate discipline can deadlock.
-        iocfg = ocfg.io if ocfg.io is not None else \
-            IOConfig(workers=ocfg.io_workers)
-        if iocfg.workers < 3:
-            iocfg = dataclasses.replace(iocfg, workers=3)
+        self.step_num = 0
+        self._closed = False
+        self.phase_time: Dict[str, float] = {"fwd": 0.0, "bwd": 0.0, "opt_wait": 0.0}
         # one shared span tracer for every layer that touches bytes
         # (executor, IOEngine threads, coordinators); off by default
         from repro.obs import Tracer
         self.tracer = Tracer()
         if ocfg.trace:
             self.tracer.enable()
-        self.ioe = IOEngine(iocfg, meter=self.meter, default_root=workdir,
-                            tracer=self.tracer)
-        self.ssd = SSDStore(workdir, self.meter, engine=self.ioe)
-        self.step_num = 0
-        self._closed = False
-        self.phase_time: Dict[str, float] = {"fwd": 0.0, "bwd": 0.0, "opt_wait": 0.0}
 
-        # ---- init params layerwise straight into tiered storage ----
+        iocfg = ocfg.io if ocfg.io is not None else \
+            IOConfig(workers=ocfg.io_workers)
+        if ranks == 1:
+            self.ranks = [_Rank(iocfg, workdir, "", self.tracer, ocfg)]
+            # the single-rank surface: eng.meter, eng.ioe, eng.p_vecs, ...
+            vars(self).update(vars(self.ranks[0]))
+        else:
+            self.ranks = [_Rank(iocfg.shard_for_rank(r, ranks),
+                                os.path.join(workdir, f"rank{r}"),
+                                f"rank{r}-", self.tracer, ocfg)
+                          for r in range(ranks)]
+
+        # ---- init params layerwise straight into tiered storage, each
+        # rank persisting only its shard ----
         keys = jax.random.split(key, self.L + 1)
-        x = ocfg.ratios
-        self.p_vecs: List[TieredVector] = []
-        self.m_master: List[TieredVector] = []
-        self.m_m: List[TieredVector] = []
-        self.m_v: List[TieredVector] = []
         tmpl = {}                   # kind -> (treedef, leaf shapes)
-        layer_P = []
+        layer_P, bounds = [], []
         for l, kind in enumerate(self.layer_kinds):
             lp = blk.block_init(keys[l], cfg, kind, dtype=self.dtype)
             flat, treedef, shapes = _flatten_tree(lp)
             flat = flat.astype(ocfg.param_dtype)
             tmpl.setdefault(kind, (treedef, shapes))
-            n = flat.size
-            layer_P.append(n)
-            pv = TieredVector(f"param:{l}", n, ocfg.param_dtype,
-                              x.param, self.host, self.ssd, "param")
-            pv.write_full(flat)
-            self.p_vecs.append(pv)
-            for name, lst, init in (("master", self.m_master, flat.astype(np.float32)),
-                                    ("m", self.m_m, np.zeros(n, np.float32)),
-                                    ("v", self.m_v, np.zeros(n, np.float32))):
-                tv = TieredVector(f"{name}:{l}", n, np.float32,
-                                  x.opt, self.host, self.ssd, "opt")
-                tv.write_full(init)
-                lst.append(tv)
+            master = flat.astype(np.float32)
+            layer_P.append(flat.size)
+            bounds.append(shard_bounds(flat.size, ranks))
+            for rk, (lo, hi) in zip(self.ranks, bounds[-1]):
+                rk.add_layer(l, flat[lo:hi], master[lo:hi], ocfg)
         #: each layer's flat vector size; ``P`` is the common one of a
         #: stack whose layers are alike, None otherwise
         self.layer_P = tuple(layer_P)
         self.P = layer_P[0] if len(set(layer_P)) == 1 else None
+        #: ``bounds[l][r]``: rank r's element range of layer l's vectors
+        self.bounds = tuple(bounds)
         self._unflattens = {k: _make_unflatten(*tmpl[k], self.dtype)
                             for k in self.kinds}
         self._unflatten = self._unflattens[self.kind]
 
-        # embedding / head resident on device (+ their own device Adam)
+        # embedding / head resident on device (+ their own device Adam),
+        # replicated on every rank: one copy suffices because all ranks
+        # apply identical reduced grads
         from repro.models.common import embed_init, init_rms_scale
         ek = jax.random.split(keys[self.L], 2)
         self.embed = embed_init(ek[0], cfg.padded_vocab, cfg.d_model, self.dtype)
@@ -535,32 +556,17 @@ class OffloadEngine:
                 "v": jnp.zeros_like(getattr(self, t), dtype=jnp.float32)}
             for t in ("embed", "unembed", "final_norm")}
 
-        # coordinators (all submit through the shared IOEngine)
-        self.params_c = ParameterCoordinator(self.p_vecs, self.meter,
-                                             self.ioe)
-        self.ckpt_c = InterLayerTensorCoordinator(x.ckpt, self.host, self.ssd,
-                                                  self.meter, self.ioe)
-        self.opt_c = OptimizerStepCoordinator(
-            self.m_master, self.m_m, self.m_v, self.p_vecs, self.host,
-            self.meter, self.ioe, CpuAdam(lr=ocfg.lr), ocfg.alpha)
-        self.act_c = ActivationCoordinator(x.act, self.host, self.ssd,
-                                           self.meter, self.ioe)
-        for c in self._coordinators():
-            c.tracer = self.tracer
-
         self._build_jit_fns()
         # size the activation stream exactly (one (layer, mb) residual
         # payload per kind) and resolve the recompute/spill/auto knob
-        act = {k: act_residual_nbytes(
+        act = {k: _act_residual_nbytes(
             self._kind_jits[k]["layer_fwd_res"], self.layer_P[
                 self.layer_kinds.index(k)], self.dtype, ocfg.micro_batch,
             ocfg.seq_len, cfg.d_model) for k in self.kinds}
         self.layer_act_nbytes = tuple(act[k] for k in self.layer_kinds)
         self.act_nbytes = act[self.kind] if len(set(act.values())) == 1 \
             else None
-        self.act_policy = resolve_activation_policy(
-            ocfg, cfg, self.layer_P, self.dtype.itemsize,
-            self.layer_act_nbytes)
+        self.act_policy = self._resolve_act_policy(ocfg)
         self.act_fallbacks = 0      # micro-batches degraded to recompute
         # cross-stream lookahead state: per-op stall meters, adaptive
         # skip counters, and the backpressure knob the executor reads
@@ -570,7 +576,7 @@ class OffloadEngine:
         self.backpressure = ocfg.backpressure
         self.act_adaptive = (ocfg.activation_policy == "auto"
                              and self.act_policy == "spill")
-        self._plan = self._compile_plan()
+        self._plan = self._compile_plan(ocfg, self.act_policy)
 
     # ------------------------------------------------------------------
     def _build_jit_fns(self):
@@ -583,7 +589,13 @@ class OffloadEngine:
             return "" if plain else "_" + kind_label(k)
         fns = build_block_fns(self.cfg, self.kind, self._unflatten,
                               suffix(self.kind))
-        bind_block_fns(self, fns)
+        self.j_layer_fwd = fns["layer_fwd"]
+        self.j_layer_fwd_res = fns["layer_fwd_res"]
+        self.j_layer_bwd_res = fns["layer_bwd_res"]
+        self.j_embed = fns["embed"]
+        self.j_head_bwd = fns["head_bwd"]
+        self.j_embed_bwd = fns["embed_bwd"]
+        self.j_adam_dev = fns["adam_dev"]
         self._kind_jits = {k: build_layer_fns(self.cfg, k,
                                               self._unflattens[k], suffix(k))
                            for k in self.kinds[1:]}
@@ -597,6 +609,31 @@ class OffloadEngine:
             for l, k in enumerate(self.layer_kinds)
             for j in (self._kind_jits[k],)]
         self.layer_labels = tuple(kind_label(k) for k in self.layer_kinds)
+
+    def _resolve_act_policy(self, ocfg: OffloadConfig) -> str:
+        """Resolve ``ocfg.activation_policy`` to "recompute"|"spill".
+        "auto" prices both policies with the perf model
+        (:func:`repro.core.perfmodel.pick_activation_policy`) using
+        ENGINE-accurate workload bytes (this engine's dtype and measured
+        residual sizes, not the bf16 paper defaults) and the machine
+        from ``ocfg.machine``, the configured bandwidth caps, or the
+        defaults."""
+        pol = ocfg.activation_policy
+        if pol != "auto":
+            return pol
+        from repro.core.perfmodel import (machine_from_bandwidth,
+                                          pick_activation_policy)
+        m = ocfg.machine
+        if m is None:
+            bw = ocfg.io.bandwidth if ocfg.io is not None else None
+            m = machine_from_bandwidth(bw) if bw else MachineParams()
+        w = engine_workload(ocfg, self.cfg, self.layer_P,
+                            self.dtype.itemsize, self.layer_act_nbytes)
+        return pick_activation_policy(w, m, ocfg.num_microbatches,
+                                      ocfg.resolved_wave_size(), ocfg.alpha,
+                                      ocfg.ratios,
+                                      lookahead=ocfg.resolved_prefetch_depth()
+                                      > 0)
 
     def _note_moe_counts(self) -> None:
         """Fold the step's per-(layer, micro-batch) held-expert token
@@ -621,22 +658,25 @@ class OffloadEngine:
     # ------------------------------------------------------------------
     def _mb_order(self, l: int) -> List[int]:
         """The canonical §4.2 alternating micro-batch order
-        (:func:`repro.core.plan.mb_order`) for this config's M. The plan
-        compiler consults THIS method, so tests can perturb the order
-        and watch the executor pay the eviction penalty."""
+        (:func:`repro.core.plan.mb_order`) for this config's M — on
+        every rank count, which is part of the bit-parity guarantee. The
+        plan compiler consults THIS method, so tests can perturb the
+        order and watch the executor pay the eviction penalty."""
         return mb_order(self.ocfg.num_microbatches, l)
 
-    def _compile_plan(self):
-        """Compile the configured schedule once; every train_step
-        interprets the same plan (with the cross-stream lookahead
-        hints at the configured depth)."""
-        depth = self.ocfg.resolved_prefetch_depth()
-        spec = PlanSpec(L=self.L, M=self.ocfg.num_microbatches,
-                        alpha=self.ocfg.alpha, ranks=1,
-                        act_spill=(self.act_policy == "spill"))
+    def _compile_plan(self, ocfg: OffloadConfig, act_policy: str):
+        """Compile ``ocfg``'s schedule for this engine's R ranks (R > 1:
+        ``ALLGATHER`` / ``REDUCE_SCATTER`` ops, rank-major micro-batch
+        blocks) with the cross-stream lookahead hints at the configured
+        depth; every train_step interprets the same plan. Raises
+        ``ValueError`` on a schedule the compiler refuses."""
+        depth = ocfg.resolved_prefetch_depth()
+        spec = PlanSpec(L=self.L, M=ocfg.num_microbatches,
+                        alpha=ocfg.alpha, ranks=self.R,
+                        act_spill=(act_policy == "spill"))
         # depth 0 = the full lookahead-off baseline: no hints AND the
         # pre-lookahead prologue OPT_LATE ordering
-        plan = compile_wave(spec, self.ocfg.resolved_wave_size(),
+        plan = compile_wave(spec, ocfg.resolved_wave_size(),
                             order=self._mb_order,
                             opt_epilogue=depth > 0)
         return insert_prefetch(plan, depth=depth)
@@ -650,23 +690,70 @@ class OffloadEngine:
 
     # ------------------------------------------------------------------
     def _split_tokens(self, tokens):
-        return split_microbatches(tokens, self.ocfg.num_microbatches,
-                                  self.ocfg.micro_batch)
+        M, mb = self.ocfg.num_microbatches, self.ocfg.micro_batch
+        assert tokens.shape[0] == M * mb
+        return tokens.reshape(M, mb, -1)
 
     def _labels(self, tok_mb):
-        return shifted_labels(tok_mb)
+        """Next-token labels/weights for one micro-batch (last position
+        masked)."""
+        lab = np.concatenate([tok_mb[:, 1:], np.zeros((tok_mb.shape[0], 1),
+                                                      tok_mb.dtype)], 1)
+        w = np.ones(tok_mb.shape, np.float32)
+        w[:, -1] = 0.0
+        return jnp.asarray(lab), jnp.asarray(w)
+
+    # ------------------------------------------------------------------
+    # simulated deterministic collectives (the ops of an R > 1 plan)
+    # ------------------------------------------------------------------
+    def _collective(self, category: str, send: int, recv: int):
+        """Charge one collective's ring cost to every rank's meter (and
+        pace it when a ``net`` route cap is configured)."""
+        for rk in self.ranks:
+            rk.meter.add(category, "gpu->net", send)
+            rk.meter.add(category, "net->gpu", recv)
+            rk.ioe.throttle("gpu->net", send)
+            rk.ioe.throttle("net->gpu", recv)
+
+    def _allgather_params(self, l: int) -> jax.Array:
+        """Each rank's shard fetch (already prefetched on its own engine)
+        concatenated into the full layer vector. Ring all-gather cost:
+        each rank sends its shard R-1 times and receives the R-1 other
+        shards."""
+        shards = [rk.params_c.get(l) for rk in self.ranks]
+        full = jnp.concatenate(shards)
+        item = self.dtype.itemsize
+        total = self.layer_P[l] * item
+        for rk, sh in zip(self.ranks, shards):
+            mine = sh.size * item
+            rk.meter.add("param", "gpu->net", (self.R - 1) * mine)
+            rk.meter.add("param", "net->gpu", total - mine)
+            rk.ioe.throttle("gpu->net", (self.R - 1) * mine)
+            rk.ioe.throttle("net->gpu", total - mine)
+        return full
+
+    def _reduce_scatter_update(self, l: int, per_mb: Dict[int, jax.Array],
+                               step: int):
+        """Deterministic reduce-scatter + per-rank partial Adam: fold the
+        per-micro-batch layer grads in GLOBAL micro-batch order (the
+        R = 1 accumulation), slice each rank's
+        element range, and hand it to that rank's optimizer coordinator.
+        Ring cost: (R-1)/R of the f32 buffer per rank, each direction."""
+        gacc = jnp.zeros((self.layer_P[l],), jnp.float32)
+        for m in self._mb_order(l):
+            gacc = gacc + per_mb[m]
+        ring = (self.R - 1) * gacc.nbytes // self.R
+        self._collective("grad", ring, ring)
+        for rk, (lo, hi) in zip(self.ranks, self.bounds[l]):
+            rk.opt_c.submit_early(l, gacc[lo:hi], step)
 
     # ------------------------------------------------------------------
     def finish(self):
         """Flush any α-pending optimizer work and drain outstanding
-        checkpoint spills (end of training): afterwards the meter
-        snapshot is complete and deterministic."""
-        for l in range(self.L):
-            self.opt_c.flush_late(l, self.step_num)
-            self.opt_c.wait_late(l)
-        self.opt_c.wait_all()
-        self.ckpt_c.wait_pending()
-        self.act_c.wait_pending()
+        checkpoint spills on every rank (end of training): afterwards
+        the meters are complete and deterministic."""
+        for rk in self.ranks:
+            rk.finish(self.L, self.step_num)
 
     # ------------------------------------------------------------------
     def apply_plan_config(self, wave_size: Optional[int] = None,
@@ -677,7 +764,7 @@ class OffloadEngine:
         autotuner's retune seam. Changes any subset of the tunable
         knobs (``wave_size`` retargets the schedule to the wave hybrid
         with that W; ``prefetch_depth``; ``activation_policy``;
-        ``path_policy`` actuates the I/O engine's chunk->path
+        ``path_policy`` actuates every rank's I/O engine chunk->path
         placement — no plan-shape change, so no recompile needed for
         it alone, but the same quiesce applies so the policy flips at
         an iteration boundary) and recompiles; the next ``train_step``
@@ -697,8 +784,9 @@ class OffloadEngine:
           prefetches and activation residue are cleared — the new plan
           re-derives its own working set.
 
-        Knobs are validated on a throwaway config copy BEFORE anything
-        mutates, so a bad value raises ``ValueError`` and leaves the
+        The new plan is compiled from a throwaway config copy BEFORE
+        anything mutates, so a bad value — or a wave under R > 1, whose
+        plans are vertical — raises ``ValueError`` and leaves the
         engine running its current plan. ``prefetch_depth`` and
         ``activation_policy`` swaps are bitwise trajectory-neutral by
         the PR-4/5 invariants; a ``wave_size`` swap is exact w.r.t. an
@@ -712,67 +800,87 @@ class OffloadEngine:
             changes["prefetch_depth"] = int(prefetch_depth)
         if activation_policy is not None:
             changes["activation_policy"] = str(activation_policy)
-        trial = dataclasses.replace(self.ocfg, **changes)
-        trial.resolved_wave_size()          # raises on a bad W
-        trial.resolved_prefetch_depth()     # raises on a bad depth
-        if trial.activation_policy not in ("recompute", "spill", "auto"):
-            raise ValueError(
-                f"unknown activation_policy "
-                f"{trial.activation_policy!r}")
+        trial = dataclasses.replace(self.ocfg, **changes)   # validates
         if path_policy is not None and path_policy not in PATH_POLICIES:
             raise ValueError(
                 f"path_policy {path_policy!r} not in {PATH_POLICIES}")
-        # quiesce: flush + wait the α tails, drain ckpt/act streams
-        self.finish()
-        if path_policy is not None:
-            self.ioe.set_path_policy(path_policy)
+        act_policy = self.act_policy if activation_policy is None else \
+            self._resolve_act_policy(trial)
+        plan = self._compile_plan(trial, act_policy)
+        # quiesce: flush + wait the α tails, drain ckpt/act streams, and
         # drop per-plan residue on every coordinator
-        self.params_c.reset()
-        self.params_c.clear_gates()
-        self.ckpt_c.clear()
-        self.act_c.clear()
-        # commit the knobs and recompile
+        self.finish()
+        for rk in self.ranks:
+            if path_policy is not None:
+                rk.ioe.set_path_policy(path_policy)
+            rk.clear()
+        # commit the knobs and the new plan
         for k, v in changes.items():
             setattr(self.ocfg, k, v)
-        if activation_policy is not None:
-            self.act_policy = resolve_activation_policy(
-                self.ocfg, self.cfg, self.layer_P, self.dtype.itemsize,
-                self.layer_act_nbytes)
-            self.act_adaptive = (self.ocfg.activation_policy == "auto"
-                                 and self.act_policy == "spill")
-        self._plan = self._compile_plan()
-        return self._plan
+        self.act_policy = act_policy
+        self.act_adaptive = (self.ocfg.activation_policy == "auto"
+                             and act_policy == "spill")
+        self._plan = plan
+        return plan
 
     # ------------------------------------------------------------------
+    def read_params(self, l: int) -> np.ndarray:
+        """Layer ``l``'s full low-precision param vector, assembled from
+        the rank shards (validation/checkpointing)."""
+        from repro.offload.checkpoint import assemble
+        return assemble(self, "p_vecs", l, np.dtype(self.ocfg.param_dtype))
+
     def save_checkpoint(self, directory: str) -> str:
         """Crash-consistent checkpoint of the full trainable state
-        (journaled manifest + CRC-verified tensors; see
-        :mod:`repro.offload.checkpoint`). Returns the manifest path."""
+        (journaled manifest + CRC-verified tensors, stored assembled so
+        any rank count restores it; see :mod:`repro.offload.checkpoint`).
+        Returns the manifest path."""
         from repro.offload.checkpoint import save_checkpoint
         return save_checkpoint(self, directory)
 
     def restore_checkpoint(self, directory: str) -> int:
-        """Restore from :meth:`save_checkpoint` output (all-or-nothing,
-        verified before any state mutates). Returns the restored
-        ``step_num``; the continued trajectory is bitwise (f32)."""
+        """Restore from :meth:`save_checkpoint` output of any rank count
+        (all-or-nothing, verified before any state mutates), re-sharded
+        by ``bounds``. Returns the restored ``step_num``; the continued
+        trajectory is bitwise (f32)."""
         from repro.offload.checkpoint import restore_checkpoint
         return restore_checkpoint(self, directory)
 
-    def traffic(self) -> Dict[str, int]:
-        out = self.meter.snapshot()
-        out["host:peak_nbytes"] = self.host.peak_nbytes
-        return out
-
-    def _coordinators(self):
-        return (self.params_c, self.ckpt_c, self.act_c, self.opt_c)
+    def traffic(self) -> List[Dict[str, int]]:
+        """Per-rank meter snapshots (index = rank), each with the rank's
+        host peak under ``"host:peak_nbytes"``."""
+        return [{**rk.meter.snapshot(), "host:peak_nbytes": rk.host.peak_nbytes}
+                for rk in self.ranks]
 
     def _lookahead_stats(self) -> Dict[str, object]:
-        return lookahead_stats(self, self._coordinators())
+        """Prefetch hit/miss counters summed over every rank's
+        coordinators, plus the adaptive-skip counters and per-op stall
+        meters."""
+        coords = [c for rk in self.ranks for c in rk.coordinators()]
+        hits = sum(c.la_hits for c in coords)
+        misses = sum(c.la_misses for c in coords)
+        total = hits + misses
+        return {"hits": hits, "misses": misses,
+                "hit_rate": hits / total if total else 1.0,
+                "hint_skips": self.hint_skips,
+                "act_skips": self.act_skips,
+                "stall_s": stall_seconds(self.op_seconds),
+                "op_seconds": dict(self.op_seconds)}
 
     def reset_stats(self):
-        """Zero every measured-iteration meter (warm-up boundary; the
-        traffic meter has its own ``reset``)."""
-        reset_lookahead_stats(self, self._coordinators())
+        """Zero EVERY measured-iteration meter — stall/phase timers,
+        adaptive-skip and fallback counters, lookahead hit/miss counts —
+        so a second measured iteration after reset reports exactly like
+        the first (warm-up boundary; the traffic meters have their own
+        ``reset``, and the I/O engines' stats are lifetime counters)."""
+        self.op_seconds.clear()
+        self.hint_skips = self.act_skips = 0
+        self.act_fallbacks = 0
+        for k in self.phase_time:
+            self.phase_time[k] = 0.0
+        for rk in self.ranks:
+            for c in rk.coordinators():
+                c.la_hits = c.la_misses = 0
 
     @property
     def plan(self):
@@ -781,36 +889,17 @@ class OffloadEngine:
         return self._plan
 
     def metrics_snapshot(self) -> Dict[str, object]:
-        """The versioned flat metrics registry snapshot — subsumes
-        :meth:`stats`, JSON-serializable; see
-        :func:`repro.obs.build_snapshot` for the schema."""
+        """The versioned flat metrics registry snapshot, per-rank fields
+        as lists, JSON-serializable; see :func:`repro.obs.build_snapshot`
+        for the schema."""
         from repro.obs import build_snapshot
         return build_snapshot(self)
 
-    def stats(self) -> Dict[str, object]:
-        """Deprecated: use :meth:`metrics_snapshot` (versioned, and a
-        strict superset of this shape — see CHANGES.md for the
-        deprecation window)."""
-        warnings.warn(
-            "OffloadEngine.stats() is deprecated; use metrics_snapshot()",
-            DeprecationWarning, stacklevel=2)
-        return {"io": self.ioe._collect_stats(),
-                "host_peak_nbytes": self.host.peak_nbytes,
-                "host_nbytes": self.host.nbytes(),
-                "act_policy": self.act_policy,
-                "act_fallbacks": self.act_fallbacks,
-                "lookahead": self._lookahead_stats(),
-                "phase_time": dict(self.phase_time)}
-
     def close(self):
         """Drain outstanding I/O, delete the workdir's tensor files, and
-        shut the transfer engine down. Idempotent."""
+        shut the transfer engines down. Idempotent."""
         if self._closed:
             return
         self._closed = True
-        self.params_c.reset()
-        self.ckpt_c.wait_pending()
-        self.act_c.wait_pending()
-        self.opt_c.wait_all()
-        self.ssd.close()              # removes stripe files from the paths
-        self.ioe.shutdown(wait=True)
+        for rk in self.ranks:
+            rk.close()

@@ -1,14 +1,15 @@
 """The ONE plan executor: walks a compiled :class:`repro.core.plan.Plan`
 against the coordinators / IOEngine stack.
 
-Both engines drive their training steps through :func:`execute_plan` —
-``OffloadEngine`` (single rank, any wave size) and
-``DataParallelOffloadEngine`` (per-rank coordinator stacks, vertical
-plans with ``ALLGATHER`` / ``REDUCE_SCATTER`` ops). The executor owns
-only transient per-step state (a register file of device tensors keyed
-by micro-batch, the layer-gradient accumulator, the head-gradient
-folds); all persistent state — tiered vectors, coordinators, the jitted
-block functions — belongs to the engine it is handed.
+``OffloadEngine`` drives every training step through
+:func:`execute_plan`, on any rank count: the op of micro-batch ``m``
+runs on the stack ``eng.ranks[m // eng.Mr]`` (one stack at R = 1, any
+wave size; per-rank stacks and vertical plans with ``ALLGATHER`` /
+``REDUCE_SCATTER`` ops at R > 1). The executor owns only transient
+per-step state (a register file of device tensors keyed by
+micro-batch, the layer-gradient accumulator, the head-gradient folds);
+all persistent state — tiered vectors, coordinators, the jitted block
+functions — belongs to the engine it is handed.
 
 Determinism: the executor performs the SAME coordinator calls and
 floating-point folds, in the SAME order, for a given schedule, so
@@ -126,45 +127,20 @@ class LayerFns:
         return self.fwd_res(p, x)[1]
 
 
-def _layer_fns(eng, L: int):
-    """Each layer's :class:`LayerFns`: the engine's per-kind list, or
-    one set of its ``j_layer_*`` jits for every layer (the DP engine)."""
-    fns = getattr(eng, "layer_fns", None)
-    if fns is not None:
-        return fns
-    return [LayerFns(eng.j_layer_fwd, eng.j_layer_fwd_res,
-                     eng.j_layer_bwd_res)] * L
-
-
 def _op_args(eng, op) -> dict:
     """Span args naming what a parameter, gradient or optimizer op of
     layer ``op.l`` moves: its kind and its bytes (the low-precision
     parameter vector, or the f32 gradient)."""
-    labels = getattr(eng, "layer_labels", None)
-    sizes = getattr(eng, "layer_P", None)
-    if labels is None or sizes is None or op.l < 0:
+    if op.l < 0:
         return {}
-    n = sizes[op.l]
     item = eng.dtype.itemsize if op.op is Op.FETCH_PARAM else 4
-    return {"kind": labels[op.l], "bytes": int(n * item)}
+    return {"kind": eng.layer_labels[op.l],
+            "bytes": int(eng.layer_P[op.l] * item)}
 
 
 #: the ops whose spans carry :func:`_op_args`
 _SIZED_OPS = frozenset((Op.FETCH_PARAM, Op.WRITEBACK_GRAD, Op.OPT_LATE,
                         Op.PREFETCH_OPT))
-
-
-def _layer_size(eng, l: int) -> int:
-    """Layer ``l``'s flat vector size (the DP engine's are all ``P``)."""
-    sizes = getattr(eng, "layer_P", None)
-    return eng.P if sizes is None else sizes[l]
-
-
-def _ranks(eng):
-    """The engine's rank stacks: the DP engine's ``ranks`` list, or the
-    single-rank engine itself (it exposes the same coordinator attrs)."""
-    rks = getattr(eng, "ranks", None)
-    return rks if rks is not None else (eng,)
 
 
 #: plan-op kinds whose handler time is GPU-blocking stall (awaiting
@@ -217,20 +193,19 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
     step = eng.step_num
     denom = jnp.asarray(float(np.prod(tokens.shape) - tokens.shape[0]),
                         jnp.float32)
-    ranks = _ranks(eng)
-    multi = len(ranks) > 1
-    Mr = eng.Mr if multi else plan.spec.M
+    ranks = eng.ranks
+    Mr = eng.Mr
 
     def rank_of(m: int):
-        return ranks[m // Mr] if multi else ranks[0]
+        return ranks[m // Mr]
 
     spill = plan.spec.act_spill     # SSDTrain-style activation stream
-    bp = getattr(eng, "backpressure", 0.5)
-    act_adaptive = getattr(eng, "act_adaptive", False)
+    bp = eng.backpressure
+    act_adaptive = eng.act_adaptive
     op_seconds = eng.op_seconds
-    tracer = getattr(eng, "tracer", None)
-    rec = tracer is not None and tracer.enabled
-    lfs = _layer_fns(eng, plan.spec.L)
+    tracer = eng.tracer
+    rec = tracer.enabled
+    lfs = eng.layer_fns
     wave = -1                       # becomes 0 at the first PHASE("fwd")
     regs = {}                       # transient device tensors
     p_dev = None                    # current layer's params
@@ -262,7 +237,7 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
                 wave += 1
             span = tracer.span(
                 EXEC_TRACK, k.name, CAT_PLAN, l=op.l, m=op.m, wave=wave,
-                rank=(op.m // Mr if multi and op.m >= 0 else 0),
+                rank=(op.m // Mr if op.m >= 0 else 0),
                 step=step, **(_op_args(eng, op) if k in _SIZED_OPS
                               else {})) if rec else NO_SPAN
             with span:
@@ -398,7 +373,7 @@ def execute_plan(eng, plan: Plan, tokens: np.ndarray) -> float:
                     else:
                         embed_stash[op.m] = d
                 elif k is Op.GRAD_INIT:
-                    gacc = jnp.zeros((_layer_size(eng, op.l),), jnp.float32)
+                    gacc = jnp.zeros((eng.layer_P[op.l],), jnp.float32)
                 elif k is Op.GRAD_SPILL:
                     rk = ranks[0]
                     g = _to_host(eng, gacc, "grad", l=op.l)

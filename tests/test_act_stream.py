@@ -26,8 +26,7 @@ from repro.core.traffic import (act_spill_traffic, dp_vertical_traffic,
                                 wave_ckpt_traffic)
 from repro.data import SyntheticLM
 from repro.io import CATEGORY_PRIORITY, IOPriority
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine)
+from repro.offload import OffloadConfig, OffloadEngine, make_engine
 
 CFG = ArchConfig(name="act-tiny", family="dense", source="test",
                  num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
@@ -45,14 +44,9 @@ def _run(policy, sched, M, W=0, alpha=0.0, ranks=0, steps=2,
                          wave_size=W, ratios=ratios,
                          activation_policy=policy)
     with tempfile.TemporaryDirectory() as d:
-        if ranks:
-            eng = DataParallelOffloadEngine(CFG, ocfg,
-                                            jax.random.PRNGKey(seed), d,
-                                            ranks=ranks)
-            meters = [rk.meter for rk in eng.ranks]
-        else:
-            eng = OffloadEngine(CFG, ocfg, jax.random.PRNGKey(seed), d)
-            meters = [eng.meter]
+        eng = make_engine(CFG, ocfg, jax.random.PRNGKey(seed), d,
+                          num_ranks=max(ranks, 1))
+        meters = [rk.meter for rk in eng.ranks]
         data = SyntheticLM(CFG.vocab_size, seed=0)
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(steps)]
@@ -60,10 +54,7 @@ def _run(policy, sched, M, W=0, alpha=0.0, ranks=0, steps=2,
         measured = [{k: v / steps for k, v in m.bytes.items()}
                     for m in meters]
         pred = plan_traffic(eng._plan, PlanCosts.from_engine(eng))
-        if ranks:
-            params = [eng.read_params(l).copy() for l in range(eng.L)]
-        else:
-            params = [eng.p_vecs[l].read().copy() for l in range(eng.L)]
+        params = [eng.read_params(l) for l in range(eng.L)]
         A = eng.act_nbytes
         assert eng.act_fallbacks == 0      # clean runs never degrade
         eng.close()

@@ -28,8 +28,7 @@ from repro.configs.base import ArchConfig
 from repro.core.perfmodel import MachineParams, StorageRatios
 from repro.data import SyntheticLM
 from repro.offload import (AutotuneConfig, AutotuneController,
-                           DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine, route_seconds_error)
+                           OffloadConfig, make_engine, route_seconds_error)
 
 CFG = ArchConfig(name="autotune-tiny", family="dense", source="test",
                  num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
@@ -54,10 +53,8 @@ def _build(sched, M, alpha, R, workdir, depth=1, wave=None):
     ocfg = OffloadConfig(schedule=sched, num_microbatches=M,
                          micro_batch=MB, seq_len=S, alpha=alpha,
                          wave_size=W, ratios=X0, prefetch_depth=depth)
-    if R > 1:
-        return DataParallelOffloadEngine(CFG, ocfg, jax.random.PRNGKey(11),
-                                         workdir, ranks=R)
-    return OffloadEngine(CFG, ocfg, jax.random.PRNGKey(11), workdir)
+    return make_engine(CFG, ocfg, jax.random.PRNGKey(11), workdir,
+                       num_ranks=R)
 
 
 def _window(eng, ctl, steps=2, seed=0):

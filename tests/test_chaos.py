@@ -249,21 +249,14 @@ def _train(schedule, alpha, ranks, spec, steps=3):
     with tempfile.TemporaryDirectory() as d:
         eng = make_engine(CFG, oc, jax.random.PRNGKey(0), d,
                           num_ranks=ranks)
-        stacks = eng.ranks if hasattr(eng, "ranks") else [eng]
-        files = [install_chaos(s.ssd, spec) for s in stacks] \
+        files = [install_chaos(rk.ssd, spec) for rk in eng.ranks] \
             if spec is not None else []
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(steps)]
         eng.finish()
-        if hasattr(eng, "ranks"):
-            params = [np.asarray(eng.read_params(l)).copy()
-                      for l in range(eng.L)]
-        else:
-            params = [np.asarray(eng.p_vecs[l].read()).copy()
-                      for l in range(eng.L)]
+        params = [eng.read_params(l) for l in range(eng.L)]
         injected = sum(f.injected["transient"] for f in files)
-        stats = eng.ioe.metrics_snapshot() if ranks == 1 else \
-            stacks[0].ioe.metrics_snapshot()
+        stats = eng.ranks[0].ioe.metrics_snapshot()
         eng.close()
     return losses, params, injected, stats
 

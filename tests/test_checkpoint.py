@@ -53,10 +53,7 @@ def _steps(eng, n, data):
 
 
 def _params(eng):
-    if hasattr(eng, "ranks"):
-        return [np.asarray(eng.read_params(l)).copy()
-                for l in range(eng.L)]
-    return [np.asarray(eng.p_vecs[l].read()).copy() for l in range(eng.L)]
+    return [eng.read_params(l) for l in range(eng.L)]
 
 
 def test_save_restore_resumes_bitwise():

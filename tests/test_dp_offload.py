@@ -11,10 +11,10 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.perfmodel import StorageRatios
+from repro.core.plan import Op, PlanSpec, compile_wave, insert_prefetch
 from repro.core.traffic import dp_vertical_traffic
 from repro.data import SyntheticLM
-from repro.offload import (DataParallelOffloadEngine, IOConfig,
-                           OffloadConfig, OffloadEngine, shard_bounds)
+from repro.offload import IOConfig, OffloadConfig, make_engine, shard_bounds
 
 CFG = get_config("gpt-tiny")
 M, MB, S = 4, 2, 64
@@ -29,26 +29,16 @@ def _ocfg(alpha=0.0, ratios=StorageRatios(0.5, 0.5, 0.0), io=None):
 def _run(alpha, ranks, steps=2, ratios=StorageRatios(0.5, 0.5, 0.0),
          io=None):
     """(losses, per-rank route dicts, final per-layer param arrays,
-    (L, P)) for a single-rank (ranks=0) or DP run."""
+    (L, P)) for a run on ``ranks`` ranks."""
     with tempfile.TemporaryDirectory() as d:
-        if ranks == 0:
-            eng = OffloadEngine(CFG, _ocfg(alpha, ratios, io),
-                                jax.random.PRNGKey(7), d)
-        else:
-            eng = DataParallelOffloadEngine(CFG, _ocfg(alpha, ratios, io),
-                                            jax.random.PRNGKey(7), d,
-                                            ranks=ranks)
+        eng = make_engine(CFG, _ocfg(alpha, ratios, io),
+                          jax.random.PRNGKey(7), d, num_ranks=ranks)
         data = SyntheticLM(CFG.vocab_size, seed=0)
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(steps)]
         eng.finish()
-        if ranks == 0:
-            routes = [dict(eng.meter.bytes)]
-            params = [np.asarray(eng.p_vecs[l].read())
-                      for l in range(eng.L)]
-        else:
-            routes = [dict(rk.meter.bytes) for rk in eng.ranks]
-            params = [eng.read_params(l) for l in range(eng.L)]
+        routes = [dict(rk.meter.bytes) for rk in eng.ranks]
+        params = [eng.read_params(l) for l in range(eng.L)]
         shape = (eng.L, eng.P)
         eng.close()
         return losses, routes, params, shape
@@ -63,7 +53,7 @@ def test_dp_bit_identical_to_single_rank(alpha):
     """R=2 sharded offload == single rank, bit-for-bit in f32: the
     ordered collectives and elementwise shard updates commute exactly
     with the single-rank fold (§6.5 extended across the DP axis)."""
-    l1, _, p1, _ = _run(alpha, ranks=0)
+    l1, _, p1, _ = _run(alpha, ranks=1)
     l2, _, p2, _ = _run(alpha, ranks=2)
     assert l1 == l2, (l1, l2)                    # Python floats: bitwise
     for layer, (a, b) in enumerate(zip(p1, p2)):
@@ -71,7 +61,7 @@ def test_dp_bit_identical_to_single_rank(alpha):
 
 
 def test_dp_four_ranks_losses_match():
-    l1, _, p1, _ = _run(0.0, ranks=0, steps=1)
+    l1, _, p1, _ = _run(0.0, ranks=1, steps=1)
     l4, _, p4, _ = _run(0.0, ranks=4, steps=1)
     assert l1 == l4
     for a, b in zip(p1, p4):
@@ -121,7 +111,7 @@ def test_single_rank_counters_match_r1_closed_form():
     """dp_vertical_traffic degenerates to the single-rank engine at R=1
     (no collectives, full shard)."""
     steps = 2
-    _, (routes,), _, (L, P) = _run(0.0, ranks=0, steps=steps,
+    _, (routes,), _, (L, P) = _run(0.0, ranks=1, steps=steps,
                                    ratios=StorageRatios(0.0, 0.0, 0.0))
     ms = L * P * 4
     cs = L * MB * S * CFG.d_model * 4
@@ -146,9 +136,9 @@ def test_dp_ranks_drive_disjoint_path_sets():
     paths, and close() must clean every path."""
     with tempfile.TemporaryDirectory() as d:
         paths = [os.path.join(d, f"nvme{i}") for i in range(4)]
-        eng = DataParallelOffloadEngine(
+        eng = make_engine(
             CFG, _ocfg(io=IOConfig(paths=paths, chunk_bytes=1 << 16)),
-            jax.random.PRNGKey(7), d, ranks=2)
+            jax.random.PRNGKey(7), d, num_ranks=2)
         assert [list(rk.ioe.paths) for rk in eng.ranks] == \
             [[paths[0], paths[2]], [paths[1], paths[3]]]
         data = SyntheticLM(CFG.vocab_size, seed=0)
@@ -159,6 +149,76 @@ def test_dp_ranks_drive_disjoint_path_sets():
         eng.close()
         for p in paths:
             assert os.listdir(p) == [], f"close() left stripes on {p}"
+
+
+def _thread_names(ioe):
+    return [t.name for w in (ioe._front, *ioe._channels) for t in w._threads]
+
+
+@pytest.mark.parametrize("schedule", ["vertical", "wave"])
+def test_single_rank_runs_the_single_rank_plan_and_layout(schedule):
+    """R = 1 compiles exactly the single-rank plan (no collective or fold
+    op), keeps its stripes under the workdir itself and leaves its I/O
+    threads unlabelled."""
+    W = {"vertical": M, "wave": 2}[schedule]
+    ocfg = OffloadConfig(schedule=schedule, wave_size=W, num_microbatches=M,
+                         micro_batch=MB, seq_len=S, alpha=0.25,
+                         ratios=StorageRatios(0.5, 0.5, 0.5))
+    with tempfile.TemporaryDirectory() as d:
+        eng = make_engine(CFG, ocfg, jax.random.PRNGKey(7), d)
+        spec = PlanSpec(L=eng.L, M=M, alpha=0.25, ranks=1)
+        assert eng.plan == insert_prefetch(compile_wave(spec, W), depth=1)
+        collective = {Op.ALLGATHER, Op.REDUCE_SCATTER, Op.ALLREDUCE_HEAD,
+                      Op.FOLD_HEAD, Op.FOLD_EMBED}
+        assert not any(op.op in collective for op in eng.plan.ops)
+        assert (eng.R, eng.Mr, len(eng.ranks)) == (1, M, 1)
+        assert eng.bounds == tuple([(0, n)] for n in eng.layer_P)
+        assert eng.meter is eng.ranks[0].meter
+        assert eng.p_vecs is eng.ranks[0].p_vecs
+        assert list(eng.ioe.paths) == [d]
+        names = _thread_names(eng.ioe)
+        assert names and not any("rank" in n for n in names), names
+        data = SyntheticLM(CFG.vocab_size, seed=0)
+        eng.train_step(data.batch(M * MB, S))
+        eng.finish()
+        files = os.listdir(d)
+        assert files and not any(f.startswith("rank") for f in files)
+        eng.close()
+
+
+def test_dp_wave_swap_is_refused_and_keeps_the_plan():
+    """``apply_plan_config(wave_size=...)`` under R = 2: the plan
+    compiler refuses a non-vertical data-parallel plan before anything
+    changes, so the running plan and config stay as they were."""
+    with tempfile.TemporaryDirectory() as d:
+        eng = make_engine(CFG, _ocfg(alpha=0.5), jax.random.PRNGKey(7), d,
+                          num_ranks=2)
+        plan = eng.plan
+        with pytest.raises(ValueError, match="vertical"):
+            eng.apply_plan_config(wave_size=2)
+        assert eng.plan is plan
+        assert eng.ocfg.schedule == "vertical"
+        assert [_thread_names(rk.ioe)[0] for rk in eng.ranks] == \
+            ["rank0-io-req-0", "rank1-io-req-0"]
+        data = SyntheticLM(CFG.vocab_size, seed=0)
+        eng.train_step(data.batch(M * MB, S))
+        eng.close()
+
+
+@pytest.mark.parametrize("kw", [{"num_microbatches": 3},
+                                {"schedule": "horizontal"}])
+def test_dp_refusals_leave_no_file_or_thread(kw):
+    """The R > 1 refusals fire before any file or thread exists."""
+    import threading
+
+    before = threading.active_count()
+    ocfg = OffloadConfig(**{**dict(num_microbatches=M, micro_batch=MB,
+                                   seq_len=S), **kw})
+    with tempfile.TemporaryDirectory() as d:
+        with pytest.raises(ValueError):
+            make_engine(CFG, ocfg, jax.random.PRNGKey(7), d, num_ranks=2)
+        assert os.listdir(d) == []
+    assert threading.active_count() <= before
 
 
 def test_shard_bounds_cover_contiguously():
@@ -173,8 +233,8 @@ def test_shard_bounds_cover_contiguously():
 def test_dp_rejects_uneven_microbatches():
     with tempfile.TemporaryDirectory() as d:
         with pytest.raises(ValueError, match="divide evenly"):
-            DataParallelOffloadEngine(CFG, _ocfg(), jax.random.PRNGKey(7),
-                                      d, ranks=3)
+            make_engine(CFG, _ocfg(), jax.random.PRNGKey(7), d,
+                        num_ranks=3)
 
 
 def test_dp_aggregate_throughput_scales():

@@ -39,8 +39,7 @@ from repro.core.plan import (HINT_FOR_FETCH, HINT_KINDS, Op, PlanCosts,
                              plan_traffic)
 from repro.data import SyntheticLM
 from repro.io import IOConfig, IOEngine, IOPriority
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine)
+from repro.offload import OffloadConfig, OffloadEngine, make_engine
 
 CFG = ArchConfig(name="look-tiny", family="dense", source="test",
                  num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
@@ -209,23 +208,13 @@ def _run(sched, M, alpha, R, depth, steps=2, io=None, policy="recompute",
                          wave_size=W, ratios=X0, prefetch_depth=depth,
                          io=io, activation_policy=policy, machine=machine)
     with tempfile.TemporaryDirectory() as d:
-        if R > 1:
-            eng = DataParallelOffloadEngine(CFG, ocfg,
-                                            jax.random.PRNGKey(11), d,
-                                            ranks=R)
-            meters = [rk.meter for rk in eng.ranks]
-        else:
-            eng = OffloadEngine(CFG, ocfg, jax.random.PRNGKey(11), d)
-            meters = [eng.meter]
+        eng = make_engine(CFG, ocfg, jax.random.PRNGKey(11), d, num_ranks=R)
         data = SyntheticLM(CFG.vocab_size, seed=0)
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(steps)]
         eng.finish()
-        routes = [dict(m.bytes) for m in meters]
-        if R > 1:
-            params = [eng.read_params(l).copy() for l in range(eng.L)]
-        else:
-            params = [eng.p_vecs[l].read().copy() for l in range(eng.L)]
+        routes = [dict(rk.meter.bytes) for rk in eng.ranks]
+        params = [eng.read_params(l) for l in range(eng.L)]
         look = eng.metrics_snapshot()["lookahead"]
         skips = (eng.hint_skips, eng.act_skips, eng.act_fallbacks)
         eng.close()

@@ -31,8 +31,7 @@ from repro.data import SyntheticLM
 from repro.io import IOConfig, IOEngine, IOPriority, StripedFiles
 from repro.obs import (SNAPSHOT_VERSION, Tracer, reconcile, stall_by_stream,
                        top_stall_stream)
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine)
+from repro.offload import OffloadConfig, make_engine, shard_bounds
 
 CFG = ArchConfig(name="obs-tiny", family="dense", source="test",
                  num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
@@ -59,10 +58,8 @@ def _build(sched, M, alpha, R, workdir, trace, io=None, policy="recompute",
                          micro_batch=MB, seq_len=S, alpha=alpha,
                          wave_size=W, ratios=X0, prefetch_depth=depth,
                          io=io, activation_policy=policy, trace=trace)
-    if R > 1:
-        return DataParallelOffloadEngine(CFG, ocfg, jax.random.PRNGKey(11),
-                                         workdir, ranks=R)
-    return OffloadEngine(CFG, ocfg, jax.random.PRNGKey(11), workdir)
+    return make_engine(CFG, ocfg, jax.random.PRNGKey(11), workdir,
+                       num_ranks=R)
 
 
 def _run(sched, M, alpha, R, trace, steps=2, **kw):
@@ -79,12 +76,8 @@ def _run(sched, M, alpha, R, trace, steps=2, **kw):
         snap = eng.metrics_snapshot()
         plan = eng.plan
         n_spans = len(eng.tracer)
-        if R > 1:
-            routes = [dict(rk.meter.bytes) for rk in eng.ranks]
-            params = [eng.read_params(l).copy() for l in range(eng.L)]
-        else:
-            routes = [dict(eng.meter.bytes)]
-            params = [eng.p_vecs[l].read().copy() for l in range(eng.L)]
+        routes = [dict(rk.meter.bytes) for rk in eng.ranks]
+        params = [eng.read_params(l) for l in range(eng.L)]
         eng.close()
     return losses, routes, params, snap, plan, n_spans
 
@@ -402,7 +395,12 @@ def test_metrics_snapshot_schema_and_json_roundtrip(R):
     # the whole contract is JSON-serializable, by construction
     again = json.loads(json.dumps(snap))
     assert again["version"] == SNAPSHOT_VERSION
-    assert (snap["bounds"] is None) == (R == 1)
+    # per layer, each rank's element range: one range covering the
+    # layer at R = 1
+    assert [[hi - lo for lo, hi in b] for b in snap["bounds"]] == \
+        [[hi - lo for lo, hi in shard_bounds(n, R)]
+         for n in snap["plan_costs"]["layer_P"]]
+    assert all(len(b) == R for b in snap["bounds"])
 
 
 # ---------------------------------------------------------------------------

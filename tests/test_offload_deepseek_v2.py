@@ -12,6 +12,7 @@ YaRN closed form, are checked on their own.
 import dataclasses
 import json
 import math
+import os
 import sys
 import tempfile
 from pathlib import Path
@@ -27,8 +28,7 @@ from repro.core.plan import PlanCosts, plan_traffic
 from repro.core.traffic import act_spill_traffic, wave_ckpt_traffic
 from repro.models import moe as moe_lib
 from repro.models.common import rope_setup, yarn_freqs, yarn_mscale
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           make_engine)
+from repro.offload import OffloadConfig, make_engine
 
 CHIP = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 
@@ -151,8 +151,8 @@ def test_dp_engine_rejects_a_mixed_stack():
     ocfg = OffloadConfig(num_microbatches=2, micro_batch=1, seq_len=8)
     with tempfile.TemporaryDirectory() as d:
         with pytest.raises(ValueError, match="data-parallel"):
-            DataParallelOffloadEngine(_cfg(), ocfg, jax.random.PRNGKey(0), d,
-                                      ranks=2)
+            make_engine(_cfg(), ocfg, jax.random.PRNGKey(0), d, num_ranks=2)
+        assert os.listdir(d) == []
 
 
 # ------------------------------------------------------------- bytes

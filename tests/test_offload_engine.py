@@ -176,7 +176,7 @@ def test_host_peak_within_lp_budget():
             eng.train_step(data.batch(M * MB, S))
         eng.finish()
         peak = eng.host.peak_nbytes
-        assert eng.traffic()["host:peak_nbytes"] == peak
+        assert eng.traffic()[0]["host:peak_nbytes"] == peak
         eng.close()
     # allowance: per-boundary transients (current-layer full tails,
     # inter-layer grads) the LP's steady-state model excludes

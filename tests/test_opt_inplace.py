@@ -30,8 +30,7 @@ from repro.core.perfmodel import StorageRatios
 from repro.data import SyntheticLM
 from repro.io import IOConfig, IOEngine
 from repro.obs import Tracer, reconcile
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine)
+from repro.offload import OffloadConfig, OffloadEngine, make_engine
 from repro.offload.coordinators import OptimizerStepCoordinator
 from repro.offload.stores import HostStore, SSDStore, TieredVector, \
     TrafficMeter
@@ -76,18 +75,13 @@ def _ocfg(sched, opt, alpha, **kw):
                                               opt=opt), **kw)
 
 
-def _stacks(eng):
-    return list(eng.ranks) if hasattr(eng, "ranks") else [eng]
-
-
 def _run(sched, opt, alpha, ranks=1):
     """Two steps, then ``finish``: losses, every rank's master/m/v and
     parameters, the metrics snapshot and the plan."""
     with tempfile.TemporaryDirectory() as d:
         ocfg = _ocfg(sched, opt, alpha)
         key = jax.random.PRNGKey(7)
-        eng = (DataParallelOffloadEngine(CFG, ocfg, key, d, ranks=ranks)
-               if ranks > 1 else OffloadEngine(CFG, ocfg, key, d))
+        eng = make_engine(CFG, ocfg, key, d, num_ranks=ranks)
         data = SyntheticLM(CFG.vocab_size, seed=0)
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(STEPS)]
@@ -95,8 +89,8 @@ def _run(sched, opt, alpha, ranks=1):
         snap = eng.metrics_snapshot()
         state = [[vec.read().copy() for vecs in (rk.m_master, rk.m_m,
                                                  rk.m_v, rk.p_vecs)
-                  for vec in vecs] for rk in _stacks(eng)]
-        sizes = [[vec.n for vec in rk.m_master] for rk in _stacks(eng)]
+                  for vec in vecs] for rk in eng.ranks]
+        sizes = [[vec.n for vec in rk.m_master] for rk in eng.ranks]
         plan = eng.plan
         eng.close()
     return losses, state, snap, plan, sizes

@@ -42,8 +42,7 @@ from repro.data import SyntheticLM
 from repro.io import IOConfig, IOEngine, IOPriority, StripedFiles
 from repro.io.engine import PATH_FAIL_DRAIN_THRESHOLD
 from repro.obs import reconcile
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine)
+from repro.offload import OffloadConfig, OffloadEngine, make_engine
 
 CHUNK = 1000        # odd size: exercises chunk-boundary arithmetic
 
@@ -379,24 +378,14 @@ def _run(sched, M, alpha, R, policy, steps=2):
                              micro_batch=MB, seq_len=S, alpha=alpha,
                              wave_size=W, ratios=X0, io=io,
                              prefetch_depth=1)
-        if R > 1:
-            eng = DataParallelOffloadEngine(CFG, ocfg,
-                                            jax.random.PRNGKey(11),
-                                            d, ranks=R)
-        else:
-            eng = OffloadEngine(CFG, ocfg, jax.random.PRNGKey(11), d)
+        eng = make_engine(CFG, ocfg, jax.random.PRNGKey(11), d, num_ranks=R)
         data = SyntheticLM(CFG.vocab_size, seed=0)
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(steps)]
         eng.finish()
-        if R > 1:
-            routes = [dict(rk.meter.bytes) for rk in eng.ranks]
-            params = [eng.read_params(l).copy() for l in range(eng.L)]
-            n_maps = sum(len(_sidecars(rk.ioe)) for rk in eng.ranks)
-        else:
-            routes = [dict(eng.meter.bytes)]
-            params = [eng.p_vecs[l].read().copy() for l in range(eng.L)]
-            n_maps = len(_sidecars(eng.ioe))
+        routes = [dict(rk.meter.bytes) for rk in eng.ranks]
+        params = [eng.read_params(l) for l in range(eng.L)]
+        n_maps = sum(len(_sidecars(rk.ioe)) for rk in eng.ranks)
         eng.close()
     return losses, routes, params, n_maps
 

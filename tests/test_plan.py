@@ -36,10 +36,10 @@ def test_mb_order_alternates():
 
 
 def test_engines_delegate_to_canonical_order():
-    """Both engines' ``_mb_order`` is the canonical repro.core.plan one
-    (the duplicate module-level copy in offload.engine re-exports it)."""
+    """The engine's ``_mb_order`` — on every rank count — is the
+    canonical repro.core.plan one (the duplicate module-level copy in
+    offload.engine re-exports it)."""
     from repro.offload import engine as eng_mod
-    from repro.offload.dp import DataParallelOffloadEngine
     from repro.offload.engine import OffloadEngine
 
     assert eng_mod.mb_order is mb_order
@@ -47,7 +47,6 @@ def test_engines_delegate_to_canonical_order():
         ocfg=types.SimpleNamespace(num_microbatches=6))
     for l in range(4):
         assert OffloadEngine._mb_order(stub, l) == mb_order(6, l)
-        assert DataParallelOffloadEngine._mb_order(stub, l) == mb_order(6, l)
 
 
 # ---------------------------------------------------------------------------

@@ -16,8 +16,7 @@ from repro.core.perfmodel import StorageRatios, machine_from_bench
 from repro.core.plan import PlanCosts, plan_traffic
 from repro.core.traffic import wave_ckpt_traffic
 from repro.data import SyntheticLM
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine)
+from repro.offload import OffloadConfig, OffloadEngine, make_engine
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -35,14 +34,9 @@ def _run(schedule, M, alpha, W=0, ranks=0, steps=2,
                          micro_batch=MB, seq_len=S, alpha=alpha,
                          wave_size=W, ratios=ratios)
     with tempfile.TemporaryDirectory() as d:
-        if ranks:
-            eng = DataParallelOffloadEngine(CFG, ocfg,
-                                            jax.random.PRNGKey(seed), d,
-                                            ranks=ranks)
-            meters = [rk.meter for rk in eng.ranks]
-        else:
-            eng = OffloadEngine(CFG, ocfg, jax.random.PRNGKey(seed), d)
-            meters = [eng.meter]
+        eng = make_engine(CFG, ocfg, jax.random.PRNGKey(seed), d,
+                          num_ranks=max(ranks, 1))
+        meters = [rk.meter for rk in eng.ranks]
         data = SyntheticLM(CFG.vocab_size, seed=0)
         losses = [eng.train_step(data.batch(M * MB, S))
                   for _ in range(steps)]

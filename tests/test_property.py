@@ -129,29 +129,22 @@ def test_lp_solution_feasible(n, alpha, cpu_gb):
 # Offload-engine schedule parity (random tiny configs)
 # ---------------------------------------------------------------------------
 
-def _engine_run(cfg, M, mb, S, alpha, ratios, seed, steps, ranks=0):
-    """Run the (single-rank or DP) offload engine; return (losses,
+def _engine_run(cfg, M, mb, S, alpha, ratios, seed, steps, ranks=1):
+    """Run the offload engine on ``ranks`` ranks; return (losses,
     final per-layer flat params, initial reference pytree)."""
     import tempfile
 
     from repro.core.perfmodel import StorageRatios
-    from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                               OffloadEngine)
+    from repro.offload import OffloadConfig, make_engine
     from repro.data import SyntheticLM
 
     ocfg = OffloadConfig(schedule="vertical", num_microbatches=M,
                          micro_batch=mb, seq_len=S, alpha=alpha, lr=1e-3,
                          ratios=StorageRatios(*ratios))
     with tempfile.TemporaryDirectory() as d:
-        if ranks:
-            eng = DataParallelOffloadEngine(cfg, ocfg,
-                                            jax.random.PRNGKey(seed), d,
-                                            ranks=ranks)
-            read_layer = eng.read_params
-        else:
-            eng = OffloadEngine(cfg, ocfg, jax.random.PRNGKey(seed), d)
-            read_layer = lambda l: np.asarray(eng.p_vecs[l].read())
-        layers = [eng._unflatten(jnp.asarray(read_layer(l)))
+        eng = make_engine(cfg, ocfg, jax.random.PRNGKey(seed), d,
+                          num_ranks=ranks)
+        layers = [eng._unflatten(jnp.asarray(eng.read_params(l)))
                   for l in range(eng.L)]
         periods = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
         init_params = {"embed": eng.embed, "prefix": (),
@@ -162,7 +155,7 @@ def _engine_run(cfg, M, mb, S, alpha, ratios, seed, steps, ranks=0):
         batches = [data.batch(M * mb, S) for _ in range(steps)]
         losses = [eng.train_step(b) for b in batches]
         eng.finish()
-        final = [read_layer(l) for l in range(eng.L)]
+        final = [eng.read_params(l) for l in range(eng.L)]
         eng.close()
     return losses, final, init_params, batches
 
@@ -173,8 +166,7 @@ def check_schedule_parity(L, dm, heads, dff, S, M, mb, alpha, seed,
 
     1. the vertical engine's losses/params are BIT-IDENTICAL (f32)
        across the α-delay and storage-ratio knobs;
-    2. when M shards evenly, the R=2 DataParallelOffloadEngine is
-       bit-identical too;
+    2. when M shards evenly, the R=2 engine is bit-identical too;
     3. the in-memory ``make_delayed_train_step`` reference matches to
        jit-boundary rounding: the engine runs per-layer jitted programs,
        the reference one scanned program, so XLA may legally fuse (and

@@ -1,8 +1,8 @@
 """repro.serve tests: the KV plan lint (meta-test of the serve hint
 contract), the three-way KV byte-exactness sweep (plan prediction ==
 measured meters == ``traffic.kv_traffic`` closed form), admission
-control (eager budget refusal, preempt-to-SSD-and-bitwise-resume), the
-``stats()`` -> ``metrics_snapshot()`` deprecation shims, and the eager
+control (eager budget refusal, preempt-to-SSD-and-bitwise-resume),
+``metrics_snapshot()`` as the one metrics surface, and the eager
 config-validation parity contract."""
 import json
 import tempfile
@@ -18,8 +18,7 @@ from repro.core.plan import Op, Plan, PlanOp, PlanSpec
 from repro.core.traffic import kv_blocks, kv_traffic
 from repro.io import IOConfig
 from repro.models import model as mdl
-from repro.offload import (DataParallelOffloadEngine, OffloadConfig,
-                           OffloadEngine, make_engine)
+from repro.offload import OffloadConfig, OffloadEngine, make_engine
 from repro.serve import (ServeConfig, ServeEngine, compile_serve_step,
                          lint_kv_plan)
 
@@ -335,7 +334,7 @@ def test_serve_config_rejects_bad_values_eagerly(kw):
 
 
 # ---------------------------------------------------------------------------
-# make_engine factory + stats() deprecation shims
+# make_engine factory + the one metrics surface
 # ---------------------------------------------------------------------------
 _OCFG = dict(num_microbatches=2, micro_batch=1, seq_len=32)
 
@@ -357,36 +356,25 @@ def test_make_engine_builds_dp():
     with tempfile.TemporaryDirectory() as d:
         eng = make_engine(CFG, OffloadConfig(**_OCFG), jax.random.PRNGKey(0),
                           d, num_ranks=2)
-        assert isinstance(eng, DataParallelOffloadEngine)
-        assert eng.R == 2
+        assert isinstance(eng, OffloadEngine)
+        assert eng.R == len(eng.ranks) == 2
         eng.close()
 
 
-def test_stats_is_deprecated_and_metrics_snapshot_is_not():
-    with tempfile.TemporaryDirectory() as d:
-        eng = OffloadEngine(CFG, OffloadConfig(**_OCFG),
-                            jax.random.PRNGKey(0), d)
-        with pytest.warns(DeprecationWarning, match="metrics_snapshot"):
-            legacy = eng.stats()
-        with pytest.warns(DeprecationWarning, match="metrics_snapshot"):
-            eng.ioe.stats()
-        # the replacement is warning-free and subsumes the legacy shape
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", DeprecationWarning)
-            snap = eng.metrics_snapshot()
-            eng.ioe.metrics_snapshot()
-        assert snap["version"] >= 1
-        assert set(legacy) <= set(snap) | {"io"}
-        eng.close()
-
-
-def test_dp_stats_is_deprecated():
+@pytest.mark.parametrize("R", [1, 2])
+def test_metrics_snapshot_is_the_only_metrics_surface(R):
+    """``metrics_snapshot()`` raises no ``DeprecationWarning`` on the
+    engine or on any rank's I/O engine, and the removed ``stats()``
+    shims are gone from both."""
     with tempfile.TemporaryDirectory() as d:
         eng = make_engine(CFG, OffloadConfig(**_OCFG), jax.random.PRNGKey(0),
-                          d, num_ranks=2)
-        with pytest.warns(DeprecationWarning, match="metrics_snapshot"):
-            eng.stats()
+                          d, num_ranks=R)
         with warnings.catch_warnings():
-            warnings.simplefilter("error", DeprecationWarning)
-            eng.metrics_snapshot()
+            warnings.simplefilter("error")
+            snap = eng.metrics_snapshot()
+            for rk in eng.ranks:
+                rk.ioe.metrics_snapshot()
+        assert snap["version"] >= 1 and snap["ranks"] == R
+        assert not hasattr(eng, "stats")
+        assert not any(hasattr(rk.ioe, "stats") for rk in eng.ranks)
         eng.close()
